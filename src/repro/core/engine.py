@@ -408,6 +408,14 @@ class M2CacheEngine:
             return None
         return _SessionKVProvider(sess)
 
+    @staticmethod
+    def last_logits(sess: DecodeSession):
+        """A real session's last-position logits (V,), read from its
+        decode-batch row while it holds one; None for analytic sessions."""
+        if sess._batch is not None:
+            return sess._batch.last[sess._row]
+        return None if sess.last is None else sess.last[0]
+
     def _runner_for(self, max_seq: int):
         # bucket to the next power of two (>= 32) so requests with nearby
         # lengths share one jit'd prefill/decode graph pair

@@ -5,10 +5,12 @@ materialises fp32 score tiles to HBM (~16 TB/step on qwen prefill_32k):
 here scores, running max/denominator and the output accumulator live in
 VMEM scratch; HBM traffic is Q/K/V/O only.
 
-Grid (B·Hkv, n_q_tiles, n_kv_tiles); the kv axis is the accumulation
+Grid (B·Hq, n_q_tiles, n_kv_tiles); the kv axis is the accumulation
 ("arbitrary") dimension. Causal + sliding-window masking via absolute
-positions. GQA: the G query heads of one KV head are folded into the q tile
-so the MXU sees (bq·G, D) × (D, bk) matmuls.
+positions. GQA: each query head reads the K/V rows of its KV head through
+the index map (query head h -> KV head h // G), so every block is a
+(rows, D) tile aligned to the TPU's (8, 128) tiling and the MXU sees
+(bq, D) × (D, bk) matmuls.
 """
 from __future__ import annotations
 
@@ -34,48 +36,46 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale       # (bq, G, D)
-    bq_, G, D = q.shape
+    q = q_ref[0].astype(jnp.float32) * scale       # (bq, D)
     k = k_ref[0].astype(jnp.float32)               # (bk, D)
     v = v_ref[0].astype(jnp.float32)               # (bk, D)
 
     s = jax.lax.dot_general(
-        q.reshape(bq_ * G, D), k,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bq*G, bk)
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (bq, bk)
 
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq_, G), 0)
-    q_pos = q_pos.reshape(bq_ * G)
-    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)[0]
-    mask = k_pos[None, :] <= q_pos[:, None]
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    mask = k_pos <= q_pos
     if window:
-        mask &= k_pos[None, :] > (q_pos[:, None] - window)
+        mask &= k_pos > (q_pos - window)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                            # (bq*G, 1)
+    m_prev = m_ref[...]                            # (bq, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ki == n_kv_tiles - 1)
     def _finish():
         # fully-masked rows (window gaps) have l == 0 -> emit zeros
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0] = (acc_ref[...] / l).reshape(bq_, G, D).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bq", "bk", "window", "interpret"))
 def flash_attention(q, k, v, *, window: int = 0, bq: int = 256,
-                    bk: int = 256, interpret: bool = True):
+                    bk: int = 256, interpret: bool):
     """Causal (+optional sliding-window) flash attention.
 
     q: (B, S, Hq, D); k, v: (B, S, Hkv, D) with Hq % Hkv == 0.
+    ``interpret`` runs the kernel in the Pallas interpreter (CPU tests)
+    instead of compiling it for the TPU.
     Returns (B, S, Hq, D) in q.dtype. S must divide by bq and bk.
     """
     B, S, Hq, D = q.shape
@@ -86,32 +86,31 @@ def flash_attention(q, k, v, *, window: int = 0, bq: int = 256,
     assert S % bq == 0 and S % bk == 0
     scale = 1.0 / math.sqrt(D)
 
-    # fold (B, Hkv) into one grid axis via reshape to (B*Hkv, ...)
-    qf = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 1, 3, 4) \
-          .reshape(B * Hkv, S, G, D)
+    # heads leading: query row b*Hq + h reads KV row b*Hkv + h // G, which
+    # is (b*Hq + h) // G because Hq = Hkv * G
+    qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, S, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, D)
 
-    grid = (B * Hkv, S // bq, S // bk)
+    grid = (B * Hq, S // bq, S // bk)
     out = pl.pallas_call(
         functools.partial(_kernel, bq=bq, bk=bk, n_kv_tiles=S // bk,
                           scale=scale, window=window),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, G, D), lambda b, i, j: (b, i, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // G, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, G, D), lambda b, i, j: (b, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, S, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * Hq, S, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq * G, 1), jnp.float32),
-            pltpu.VMEM((bq * G, 1), jnp.float32),
-            pltpu.VMEM((bq * G, D), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4) \
-              .reshape(B, S, Hq, D)
+    return out.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)

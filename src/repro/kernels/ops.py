@@ -51,9 +51,9 @@ def make_compact_banks(wg, wu, wd, sizes: Dict[str, int], idx) -> Dict:
     return out
 
 
-def mp_glu_ffn(x, banks: Dict, *, act_name: str = "silu",
-               interpret: bool = True):
-    """x: (B, d). banks: output of make_compact_banks. Returns (B, d) f32."""
+def mp_glu_ffn(x, banks: Dict, *, act_name: str = "silu", interpret: bool):
+    """x: (B, d). banks: output of make_compact_banks. ``interpret`` is
+    passed to every kernel (True on CPU). Returns (B, d) f32."""
     B, d = x.shape
     y = jnp.zeros((B, d), jnp.float32)
     act = activation(act_name)

@@ -38,14 +38,15 @@ def flash_decode_ref(q, k, v, slot_positions, lengths):
 
 
 def atu_update_ref(bank, unit, src_idx, dst_idx, *, bg: int = 8):
-    """Block-group column copies: groups of bg columns move together."""
+    """Block-group row copies: groups of bg neuron rows move together.
+    bank: (f, d); unit: (k, d)."""
     out = jnp.asarray(unit)
     m = src_idx.shape[0]
     for g in range(m // bg):
         sbase = int(src_idx[g * bg]) // bg * bg
         dbase = int(dst_idx[g * bg]) // bg * bg
-        out = out.at[:, dbase:dbase + bg].set(
-            bank[:, sbase:sbase + bg].astype(unit.dtype))
+        out = out.at[dbase:dbase + bg].set(
+            bank[sbase:sbase + bg].astype(unit.dtype))
     return out
 
 

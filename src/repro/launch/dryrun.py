@@ -1,11 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 """Multi-pod dry-run: lower + compile every (arch × input shape × mesh),
 extract memory/cost analysis and collective schedule, write one JSON per
 combo (resumable).
 
-The two lines above MUST stay first: jax locks the device count at first
-initialisation, and the production meshes need 512 placeholder devices.
+The three lines above MUST stay first: jax locks the device count at first
+initialisation, and the production meshes need 512 placeholder CPU devices
+(on a TPU host jax would otherwise pick the chip).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun \

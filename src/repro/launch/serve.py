@@ -19,10 +19,12 @@ import numpy as np
 
 from repro.core.engine import PAPER_MODELS, M2CacheEngine
 from repro.configs.base import get_config
+from repro.launch.jax_cache import setup_compile_cache
 from repro.models import transformer as T
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--paper-model", default=None,

@@ -19,6 +19,10 @@ Real tiny model (actual decode, modeled clock):
   PYTHONPATH=src python -m repro.launch.server --arch qwen2.5-14b --tiny \
       --requests 6 --rate 2.0 --max-batch 4
 
+Published widths, depth cut to two layers (one TPU v5e chip):
+  PYTHONPATH=src python -m repro.launch.server --arch qwen2.5-14b \
+      --layers 2 --requests 8 --max-batch 4 --prefill-chunk 16
+
 Radix prefix cache on chat-style shared-prefix traffic (KV reuse across
 requests, batched prefill):
   PYTHONPATH=src python -m repro.launch.server --arch qwen2.5-14b --tiny \
@@ -38,6 +42,7 @@ million-user-sample traffic, carbon-driven autoscaling (docs/CLUSTER.md):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 
 from repro.core.carbon import CarbonIntensityTrace
@@ -48,6 +53,16 @@ from repro.serving import (ROUTER_POLICIES, CarbonAutoscaler,
                            diurnal_trace, make_policy, poisson_trace,
                            requests_from_trace, shared_prefix_trace,
                            shifted_trace)
+
+
+def build_config(args):
+    """The ``--arch`` config, cut to ``--layers`` when given (depth only:
+    every width stays as published)."""
+    from repro.configs.base import get_config
+    cfg = get_config(args.arch, tiny=args.tiny)
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
 
 
 def build_engine(args, device_name=None) -> M2CacheEngine:
@@ -61,9 +76,8 @@ def build_engine(args, device_name=None) -> M2CacheEngine:
                              prefill_bucket=args.prefill_bucket, **dev)
     import jax
     import jax.numpy as jnp
-    from repro.configs.base import get_config
     from repro.models import transformer as T
-    cfg = get_config(args.arch, tiny=args.tiny)
+    cfg = build_config(args)
     key = jax.random.PRNGKey(args.seed)
     params = T.init_params(key, cfg, dtype=jnp.float32, m2=True)
     return M2CacheEngine(cfg=cfg, params=params, mode=args.mode,
@@ -187,14 +201,39 @@ def run_cluster(args, prefix_on: bool):
         recorder.export_chrome(args.trace_out)
         out["obs"] = recorder.stats()
     print(json.dumps(out, indent=1, default=float))
+    return out
 
 
-def main():
+def streams(reqs, eng) -> list:
+    """Per request of a real-model run: the tokens it emitted (those from
+    before a recovery included), how many it asked for, and whether its
+    last-position logits are all finite (they attend over the request's
+    whole KV, so a non-finite value anywhere in its cache shows there)."""
+    import numpy as np
+    out = []
+    for r in sorted(reqs, key=lambda r: r.rid):
+        last = None if r.session is None else eng.last_logits(r.session)
+        out.append({"rid": r.rid, "max_new_tokens": r.max_new_tokens,
+                    "tokens": r.final_tokens(),
+                    "logits_finite": last is not None
+                    and bool(np.isfinite(np.asarray(last)).all())})
+    return out
+
+
+def main(argv=None) -> dict:
+    """Parse ``argv`` (default: the command line), serve, print the run's
+    JSON report and return it (the cluster report with ``--replicas > 1``).
+    """
+    from repro.launch.jax_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--paper-model", default=None,
                     choices=list(PAPER_MODELS) + [None])
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="cut --arch to its first N layers at published "
+                         "widths (depth only; not with --tiny)")
     ap.add_argument("--mode", default="m2cache",
                     choices=["m2cache", "zero_infinity"])
     ap.add_argument("--hbm-policy", default="atu",
@@ -365,7 +404,11 @@ def main():
                          "(default: the built-in serving rule set; "
                          "schema in docs/OBSERVABILITY.md)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.layers is not None and (args.tiny or not args.arch
+                                    or args.layers < 1):
+        ap.error("--layers N (N >= 1) cuts an --arch config at published "
+                 "widths; it is refused with --tiny and --paper-model")
     if args.alert_rules and not args.health_out:
         ap.error("--alert-rules requires --health-out")
     # unset --prefix-cache means off single-replica, on in cluster mode
@@ -391,8 +434,7 @@ def main():
         if args.grid_shift and not build_trace(args).period_s:
             ap.error("--grid-shift needs a periodic --carbon-trace "
                      "(square or diurnal)")
-        run_cluster(args, prefix_on)
-        return
+        return run_cluster(args, prefix_on)
     if args.grid_shift or args.autoscale or args.replica_devices \
             or args.horizon is not None:
         ap.error("--grid-shift/--autoscale/--replica-devices/--horizon "
@@ -479,9 +521,16 @@ def main():
         "summary": rep.summary(),
         "kv": rep.kv_stats,
         "cache": rep.cache_stats,
-        "prefix": rep.prefix_stats,
+        "prefix": dict(rep.prefix_stats, prefix_restored_tokens=
+                       eng.prefix_restored_tokens)
+        if rep.prefix_stats and eng.cfg is not None else rep.prefix_stats,
         "persist": persist,
         "carbon_g": rep.carbon,
+        # host clock: seconds from the scheduler's start to its first
+        # decode step's tokens (compiles included) and to its end
+        "wall": {"first_token_s": rep.wall_first_token_s,
+                 "first_step_tokens": rep.first_step_tokens,
+                 "run_s": rep.wall_s},
     }
     if obs:
         out["obs"] = obs
@@ -497,8 +546,11 @@ def main():
                          "active": health.active()}
     if injector is not None:
         out["faults"] = injector.stats()
-        out["failures"] = rep.failures()
+    out["failures"] = rep.failures()
+    if eng.cfg is not None:
+        out["streams"] = streams(rep.requests + rep.failed, eng)
     print(json.dumps(out, indent=1, default=float))
+    return out
 
 
 if __name__ == "__main__":

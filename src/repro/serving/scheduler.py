@@ -29,6 +29,7 @@ batch — §5.5.2), so ``max_batch`` defaults stay small.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -131,6 +132,13 @@ class ServingReport:
     failed: List[ServingRequest] = dataclasses.field(default_factory=list)
     recoveries: int = 0                 # re-enqueue + re-prefill events
     fault_stats: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # host clock, not the modeled one: seconds from run() start to the end
+    # of the first decode step (its tokens are on the host, so the device
+    # work behind them has finished; compiles land here) and to the end of
+    # the run, and the tokens that first step emitted
+    wall_s: float = 0.0
+    wall_first_token_s: Optional[float] = None
+    first_step_tokens: int = 0
 
     def failures(self) -> List[dict]:
         """The structured error slots of failed requests (JSON-ready)."""
@@ -829,6 +837,9 @@ class ContinuousBatchScheduler:
         either way). Latencies and tokens/s are unaffected; if the run
         outlives the horizon, billing simply ends at the true span.
         """
+        wall0 = time.perf_counter()
+        wall_first_token = None
+        first_step_tokens = 0
         eng, kv = self.engine, self.kv
         pending = sorted(requests, key=lambda r: r.arrival_s)
         waiting: List[ServingRequest] = []
@@ -972,6 +983,9 @@ class ContinuousBatchScheduler:
                 if eng.manager is not None:
                     eng.manager.dispatch_log.clear()
                 rep = eng.decode_step([r.session for r in running])
+                if wall_first_token is None:
+                    wall_first_token = time.perf_counter() - wall0
+                    first_step_tokens = len(running)
                 dec_disp = self._drain_dispatches("decode")
                 dec_dt = eng.clock - t_dec0
                 self._bill_step(
@@ -1153,5 +1167,8 @@ class ContinuousBatchScheduler:
             prefill_dispatches=prefill_dispatches,
             prefix_stats=prefix_stats,
             failed=failed, recoveries=recoveries,
+            wall_s=time.perf_counter() - wall0,
+            wall_first_token_s=wall_first_token,
+            first_step_tokens=first_step_tokens,
             fault_stats=self.faults.stats()
             if self.faults is not None else {})

@@ -5,14 +5,12 @@ import jax
 import pytest
 
 
-#: skip-on-CPU marker for tests that need a real accelerator backend
-#: (Pallas lowering, HLO cost models, multi-device topologies) — the
-#: pre-existing seed failures on this CPU-only container, per
-#: docs/LIMITATIONS.md. On GPU/TPU hosts these tests run normally.
+#: skip-on-CPU marker for tests that need a TPU backend (HLO cost models,
+#: multi-device topologies), per docs/LIMITATIONS.md
 needs_accelerator = pytest.mark.skipif(
     jax.devices()[0].platform == "cpu",
-    reason="needs a GPU/TPU XLA backend; fails on the CPU-only container "
-           "(docs/LIMITATIONS.md)")
+    reason="needs the TPU backend: HLO cost models and multi-device "
+           "topologies differ on the CPU backend (docs/LIMITATIONS.md)")
 
 
 @pytest.fixture(scope="session")

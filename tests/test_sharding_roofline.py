@@ -169,7 +169,9 @@ _CHILD = textwrap.dedent("""
 def test_multi_device_lowering_subprocess():
     archs = ["qwen2.5-14b", "grok-1-314b", "recurrentgemma-2b",
              "mamba2-370m", "musicgen-large"]
-    env = dict(os.environ, PYTHONPATH="src")
+    # the parent already holds a backend: pin the child to the CPU so it
+    # never reaches for an accelerator the parent owns
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", _CHILD % repr(archs)],
         capture_output=True, text=True, env=env, timeout=900,
