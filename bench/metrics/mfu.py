@@ -1,0 +1,13 @@
+"""Model FLOP utilization of the whole served step, in %: operations the
+model needs for every token the window's prefill and decode steps processed
+(bench/flops.py), per second of the window, over the chip's bf16 peak."""
+from bench import flops as F
+from bench import window as Wn
+
+
+def read(ctx):
+    steps = Wn.steps_in_window(ctx.rec.decode + ctx.rec.prefill, ctx)
+    if not steps:
+        return None
+    total = sum(F.step_flops(ctx.D, s.tokens, s.ctx, s.out) for s in steps)
+    return 100.0 * total / ctx.seconds / ctx.peaks["bf16_flops"]
