@@ -30,6 +30,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
+from repro.obs.trace import host_span
+
 
 @dataclasses.dataclass
 class PrefetchStats:
@@ -225,20 +227,28 @@ class Preloader:
         self.stats = PreloadStats()
         self.engine = prefetch if prefetch is not None else PrefetchEngine()
         self.engine.add_channel(SSD_CHANNEL, ssd_bw)
+        self._obs_trace = None          # repro.obs.TraceRecorder
+
+    def attach_obs(self, trace):
+        """Record each layer load as an ``m2.ssd_load`` host span."""
+        self._obs_trace = trace
 
     def _key(self, layer: int):
         return ("w", layer)
 
     def _load(self, layer: int, now: float) -> float:
         """Queue one layer's SSD→DRAM load; returns its finish time."""
-        banks = self.ssd.read_layer(layer)
-        frac = self.miss_frac if layer in self._seen else 1.0
-        self._seen.add(layer)
-        nbytes = sum(a.nbytes for a in banks.values()) * self.byte_scale \
-            * frac
-        finish = self.engine.issue(SSD_CHANNEL, self._key(layer), nbytes,
-                                   now)
-        self.dram.insert(layer, banks)
+        with host_span(self._obs_trace, "m2", "ssd_load",
+                       layer=layer) as span:
+            banks = self.ssd.read_layer(layer)
+            frac = self.miss_frac if layer in self._seen else 1.0
+            self._seen.add(layer)
+            nbytes = sum(a.nbytes for a in banks.values()) \
+                * self.byte_scale * frac
+            finish = self.engine.issue(SSD_CHANNEL, self._key(layer),
+                                       nbytes, now)
+            self.dram.insert(layer, banks)
+            span.note(bytes=int(nbytes))
         self.stats.layers_loaded += 1
         self.stats.bytes_loaded += nbytes
         return finish

@@ -34,6 +34,7 @@ from repro.core.cache.preloader import (PCIE_CHANNEL, SSD_CHANNEL,
 from repro.core.cache.ssd_tier import SSDTier
 from repro.core.hw import HOST, HostHW
 from repro.core.mp_ffn import tier_sizes
+from repro.obs.trace import host_span
 
 
 @dataclasses.dataclass
@@ -250,6 +251,7 @@ class M2CacheEngine:
         self.decode_dispatches = 0       # jit decode graphs launched
         self.prefill_dispatches = 0      # jit prefill graphs launched
         self._batches: Dict[int, object] = {}   # bucket max_seq -> DecodeBatch
+        self._obs_trace = None           # repro.obs.TraceRecorder (host spans)
 
         if cfg is not None:
             self.num_layers = cfg.num_layers
@@ -283,6 +285,14 @@ class M2CacheEngine:
                 prefetch=self.prefetch)
 
     # ------------------------------------------------------------------
+    def attach_obs(self, trace):
+        """Record host-clock spans of the engine's steps and of the M2
+        neuron cache's SSD loads into ``trace`` (a
+        :class:`~repro.obs.TraceRecorder`); None detaches."""
+        self._obs_trace = trace
+        if self.manager is not None:
+            self.manager.preloader.attach_obs(trace)
+
     def _ssd_miss_frac(self) -> float:
         """Steady-state SSD fetch fraction when a layer is re-loaded:
         only the active set's mixed-precision bytes are missing (paper
@@ -516,24 +526,31 @@ class M2CacheEngine:
         n = remaining if max_tokens is None else min(max_tokens, remaining)
         assert n >= 1
         dispatches = 0
+        tr = self._obs_trace
         if self.mode == "zero_infinity":
             rep = self._zero_infinity_step(n)
         else:
             if self.params is not None and sess.prompt is not None:
-                if self._chunked_real:
-                    dispatches = self._advance_exec(
-                        [sess], {id(sess): sess.prompt_done + n}, bucket=1)
-                    sets = self._chunk_sets_for(sess, sess.prompt_done + n)
-                else:
-                    if sess.runner is None:
-                        dispatches = 1   # first chunk runs the jit prefill
-                    sets = self._real_chunk_sets(sess, n)
+                with host_span(tr, "engine", "prefill.dispatch",
+                               rid=sess.rid, rows=1):
+                    if self._chunked_real:
+                        dispatches = self._advance_exec(
+                            [sess], {id(sess): sess.prompt_done + n},
+                            bucket=1)
+                        sets = self._chunk_sets_for(sess,
+                                                    sess.prompt_done + n)
+                    else:
+                        if sess.runner is None:
+                            dispatches = 1   # first chunk runs the prefill
+                        sets = self._real_chunk_sets(sess, n)
             else:
                 sets = [pr.step() for pr in sess.procs] if sess.procs else \
                     [np.zeros(0, np.int64)] * self.num_layers
-            tiers = [_tier_map(s, self.sizes) for s in sets]
             overlapped0 = self.prefetch.stats.overlapped_bytes
-            tok = self.manager.process_token(sets, tiers, batch_size=n)
+            with host_span(tr, "engine", "prefill.m2_manager",
+                           rid=sess.rid, groups=1):
+                tiers = [_tier_map(s, self.sizes) for s in sets]
+                tok = self.manager.process_token(sets, tiers, batch_size=n)
             rep = StepReport(modeled_s=tok.modeled_s,
                              compute_s=tok.compute_s, batch_size=n,
                              report=tok, stall_s=tok.ssd_stall_s,
@@ -597,8 +614,11 @@ class M2CacheEngine:
                              dtype=sess.runner.dtype)
         if sess.prefix_kv:
             bt = self.kv_block_tokens
-            for i, payload in enumerate(sess.prefix_kv):
-                cache = KP.inject(cache, payload, i * bt)
+            with host_span(self._obs_trace, "engine", "restore_prefix",
+                           rid=sess.rid, blocks=len(sess.prefix_kv),
+                           tokens=sess.exec_done):
+                for i, payload in enumerate(sess.prefix_kv):
+                    cache = KP.inject(cache, payload, i * bt)
             cache["pos"] = jnp.asarray(sess.exec_done, jnp.int32)
             self.prefix_restored_tokens += sess.exec_done
             sess.prefix_kv = None
@@ -765,6 +785,13 @@ class M2CacheEngine:
                     if s.prompt_done < s.prompt_len]
         if not sessions:
             return None
+        with host_span(self._obs_trace, "engine", "prefill_step",
+                       rows=len(sessions)):
+            return self._prefill_sessions(sessions, max_tokens)
+
+    def _prefill_sessions(self, sessions, max_tokens) -> StepReport:
+        """:meth:`prefill_step` over the sessions with prompt left."""
+        tr = self._obs_trace
         if self.prefill_bucket <= 1 or self.mode == "zero_infinity" \
                 or len(sessions) == 1:
             # per-session fallback: serial charging, one graph per first
@@ -794,13 +821,14 @@ class M2CacheEngine:
                 and s.prompt is not None]
         real_ids = {id(s) for s in real}
         other = [s for s in sessions if id(s) not in real_ids]
-        if self._chunked_real:
-            dispatches = self._advance_exec(
-                real, {id(s): s.prompt_done + ns[id(s)] for s in real},
-                bucket=self.prefill_bucket)
-        else:
-            dispatches = self._stacked_real_prefill(
-                [s for s in real if s.runner is None])
+        with host_span(tr, "engine", "prefill.dispatch", rows=len(real)):
+            if self._chunked_real:
+                dispatches = self._advance_exec(
+                    real, {id(s): s.prompt_done + ns[id(s)] for s in real},
+                    bucket=self.prefill_bucket)
+            else:
+                dispatches = self._stacked_real_prefill(
+                    [s for s in real if s.runner is None])
         # dispatch groups for pricing: real sessions per runner bucket,
         # analytic sessions together
         groups: List[list] = []
@@ -810,40 +838,42 @@ class M2CacheEngine:
         groups.extend(buckets.values())
         if other:
             groups.append(other)
-        t_compute = stall = 0.0
-        for members in groups:
-            gtokens = sum(ns[id(s)] for s in members)
-            per_sess_sets = []
-            for s in members:
-                if id(s) in real_ids:
-                    per_sess_sets.append(
-                        self._chunk_sets_for(s, s.prompt_done + ns[id(s)])
-                        if self._chunked_real
-                        else self._real_chunk_sets(s, ns[id(s)]))
-                elif s.procs:
-                    per_sess_sets.append([pr.step() for pr in s.procs])
-                else:
-                    per_sess_sets.append(
-                        [np.zeros(0, np.int64)] * self.num_layers)
-            rows_per_layer = [
-                np.stack([sets[l] for sets in per_sess_sets])
-                for l in range(self.num_layers)]
-            sets, tiers = self._union_active(rows_per_layer)
-            tok = self.manager.process_token(sets, tiers,
-                                             batch_size=gtokens)
-            t_compute += tok.compute_s
-            stall += tok.ssd_stall_s
-            # bill each member its token-weighted share for reporting
-            for s in members:
-                share = ns[id(s)] / max(gtokens, 1)
-                prev = s.prefill_report
-                s.prefill_report = StepReport(
-                    modeled_s=tok.modeled_s * share
-                    + (prev.modeled_s if prev else 0.0),
-                    compute_s=tok.compute_s * share
-                    + (prev.compute_s if prev else 0.0),
-                    batch_size=s.prompt_done + ns[id(s)], report=tok)
-                s.prompt_done += ns[id(s)]
+        with host_span(tr, "engine", "prefill.m2_manager",
+                       groups=len(groups)):
+            t_compute = stall = 0.0
+            for members in groups:
+                gtokens = sum(ns[id(s)] for s in members)
+                per_sess_sets = []
+                for s in members:
+                    if id(s) in real_ids:
+                        per_sess_sets.append(
+                            self._chunk_sets_for(s, s.prompt_done + ns[id(s)])
+                            if self._chunked_real
+                            else self._real_chunk_sets(s, ns[id(s)]))
+                    elif s.procs:
+                        per_sess_sets.append([pr.step() for pr in s.procs])
+                    else:
+                        per_sess_sets.append(
+                            [np.zeros(0, np.int64)] * self.num_layers)
+                rows_per_layer = [
+                    np.stack([sets[l] for sets in per_sess_sets])
+                    for l in range(self.num_layers)]
+                sets, tiers = self._union_active(rows_per_layer)
+                tok = self.manager.process_token(sets, tiers,
+                                                 batch_size=gtokens)
+                t_compute += tok.compute_s
+                stall += tok.ssd_stall_s
+                # bill each member its token-weighted share for reporting
+                for s in members:
+                    share = ns[id(s)] / max(gtokens, 1)
+                    prev = s.prefill_report
+                    s.prefill_report = StepReport(
+                        modeled_s=tok.modeled_s * share
+                        + (prev.modeled_s if prev else 0.0),
+                        compute_s=tok.compute_s * share
+                        + (prev.compute_s if prev else 0.0),
+                        batch_size=s.prompt_done + ns[id(s)], report=tok)
+                    s.prompt_done += ns[id(s)]
         self.prefill_dispatches += dispatches
         return StepReport(
             modeled_s=self.clock - clock0, compute_s=t_compute,
@@ -907,12 +937,21 @@ class M2CacheEngine:
         ``jit_dispatches`` the number of decode graphs launched. KV
         growth is *not* included — the scheduler charges it separately
         via the tiered KV cache."""
-        B = len(sessions)
-        assert B >= 1
+        assert len(sessions) >= 1
+        with host_span(self._obs_trace, "engine", "decode_step",
+                       rows=len(sessions)):
+            return self._decode_sessions(sessions)
+
+    def _decode_sessions(self, sessions) -> StepReport:
+        """:meth:`decode_step`: the sync of the decode batches, the jitted
+        dispatch until the tokens are on the host, then the M2
+        neuron-cache bookkeeping (active-index pull, batch union,
+        ``process_token``), each a host span."""
         if self.mode == "zero_infinity":
             for sess in sessions:
                 sess.tokens.append(None)
-            return self._zero_infinity_step(B)
+            return self._zero_infinity_step(len(sessions))
+        tr = self._obs_trace
         clock0 = self.clock
         overlapped0 = self.prefetch.stats.overlapped_bytes
         # mode is per session: a real engine can still serve analytic
@@ -920,41 +959,42 @@ class M2CacheEngine:
         real = [s for s in sessions if s.runner is not None]
         analytic = [s for s in sessions if s.runner is None]
         dispatches = 0
-        groups: List[tuple] = []        # (rows_per_layer, group size)
+        pulls: List[tuple] = []         # (active-idx aux, rows, group size)
 
         if real and self.batched_decode and self.cfg.family != "audio":
-            from repro.core.engine_model import flatten_active_idx_batched
             buckets: Dict[int, list] = {}
             for s in real:
                 buckets.setdefault(id(s.runner), []).append(s)
             for members in buckets.values():
                 batch = self._batch_for(members[0].runner)
-                batch.sync(members)
-                nxt, aux = batch.step(self.params)
+                with host_span(tr, "engine", "decode.sync",
+                               rows=len(members)):
+                    batch.sync(members)
+                with host_span(tr, "engine", "decode.dispatch",
+                               rows=len(members)):
+                    nxt, aux = batch.step(self.params)
+                    for s in members:
+                        s.tokens.append(int(nxt[s._row]))
                 dispatches += 1
-                for s in members:
-                    s.tokens.append(int(nxt[s._row]))
-                rows_idx = [s._row for s in members]
-                per_layer = flatten_active_idx_batched(self.cfg, aux)
-                groups.append(([arr[rows_idx] for arr in per_layer],
-                               len(members)))
+                pulls.append((aux, [s._row for s in members],
+                              len(members)))
         elif real:
             import jax.numpy as jnp
-            from repro.core.engine_model import flatten_active_idx
             for sess in real:
-                nxt = jnp.argmax(sess.last, axis=-1).astype(jnp.int32)
-                sess.tokens.append(int(np.asarray(nxt)[0]))
-                if self.cfg.family == "audio":
-                    tok = jnp.broadcast_to(
-                        nxt[:, None, None],
-                        (nxt.shape[0], self.cfg.num_codebooks, 1))
-                else:
-                    tok = nxt[:, None]
-                sess.last, sess.cache, aux = sess.runner._decode(
-                    self.params, sess.cache, tok)
+                with host_span(tr, "engine", "decode.dispatch", rows=1):
+                    nxt = jnp.argmax(sess.last, axis=-1).astype(jnp.int32)
+                    sess.tokens.append(int(np.asarray(nxt)[0]))
+                    if self.cfg.family == "audio":
+                        tok = jnp.broadcast_to(
+                            nxt[:, None, None],
+                            (nxt.shape[0], self.cfg.num_codebooks, 1))
+                    else:
+                        tok = nxt[:, None]
+                    sess.last, sess.cache, aux = sess.runner._decode(
+                        self.params, sess.cache, tok)
                 dispatches += 1
-                groups.append(([np.asarray(a)[None] for a in
-                                flatten_active_idx(self.cfg, aux)], 1))
+                pulls.append((aux, None, 1))
+        groups: List[tuple] = []        # (rows_per_layer, group size)
         if analytic:
             for sess in analytic:
                 sess.tokens.append(None)
@@ -967,19 +1007,35 @@ class M2CacheEngine:
                 per_layer = [np.zeros((0, 0), np.int64)] * self.num_layers
             groups.append((per_layer, len(analytic)))
 
-        t_compute = stall = 0.0
-        last_report = None
-        for rows_per_layer, gsize in groups:
-            sets, tiers = self._union_active(rows_per_layer)
-            rep = self.manager.process_token(sets, tiers, batch_size=gsize)
-            t_compute += rep.compute_s
-            stall += rep.ssd_stall_s
-            last_report = rep
+        with host_span(tr, "engine", "decode.m2_manager",
+                       groups=len(pulls) + len(groups)):
+            real_groups = []
+            if pulls:
+                from repro.core.engine_model import (
+                    flatten_active_idx, flatten_active_idx_batched)
+            for aux, rows_idx, gsize in pulls:
+                if rows_idx is None:
+                    real_groups.append(([np.asarray(a)[None] for a in
+                                         flatten_active_idx(self.cfg, aux)],
+                                        1))
+                else:
+                    per_layer = flatten_active_idx_batched(self.cfg, aux)
+                    real_groups.append(([arr[rows_idx]
+                                         for arr in per_layer], gsize))
+            t_compute = stall = 0.0
+            last_report = None
+            for rows_per_layer, gsize in real_groups + groups:
+                sets, tiers = self._union_active(rows_per_layer)
+                rep = self.manager.process_token(sets, tiers,
+                                                 batch_size=gsize)
+                t_compute += rep.compute_s
+                stall += rep.ssd_stall_s
+                last_report = rep
         self.decode_dispatches += dispatches
         return StepReport(
             modeled_s=self.clock - clock0, compute_s=t_compute,
-            batch_size=B, report=last_report, jit_dispatches=dispatches,
-            stall_s=stall,
+            batch_size=len(sessions), report=last_report,
+            jit_dispatches=dispatches, stall_s=stall,
             overlapped_bytes=self.prefetch.stats.overlapped_bytes
             - overlapped0)
 

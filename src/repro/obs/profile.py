@@ -24,6 +24,8 @@ from __future__ import annotations
 
 from typing import Dict, Iterable, List, Optional
 
+from repro.obs.trace import MODELED
+
 _EPS = 1e-9          # containment slack for float-rounded span edges
 
 
@@ -31,18 +33,24 @@ _EPS = 1e-9          # containment slack for float-rounded span edges
 # normalized-event adapters
 
 def events_from_recorder(recorder) -> List[dict]:
-    """Normalize a live :class:`TraceRecorder`'s ring into event dicts."""
+    """Normalize a live :class:`TraceRecorder`'s ring into event dicts
+    (modeled-clock events only; host spans live on another axis)."""
     return [{"kind": ev.kind, "track": ev.track, "name": ev.name,
              "t": ev.t, "dur": ev.dur, "args": dict(ev.args or {})}
-            for ev in recorder.events()]
+            for ev in recorder.events() if ev.clock == MODELED]
 
 
 def events_from_chrome(doc) -> List[dict]:
     """Normalize a Chrome ``trace_event`` document (the dict
     ``TraceRecorder.export_chrome`` writes, or its ``traceEvents``
     list) back into event dicts; µs timestamps become modeled seconds
-    and ``tid``s resolve to track names via the ``M`` metadata."""
+    and ``tid``s resolve to track names via the ``M`` metadata. The
+    host-clock process (``otherData.host_pid``) is left out."""
+    host_pid = doc.get("otherData", {}).get("host_pid") \
+        if isinstance(doc, dict) else None
     events = doc.get("traceEvents", doc) if isinstance(doc, dict) else doc
+    if host_pid is not None:
+        events = [ev for ev in events if ev.get("pid") != host_pid]
     tracks: Dict[int, str] = {}
     for ev in events:
         if ev.get("ph") == "M" and ev.get("name") == "thread_name":
@@ -54,7 +62,6 @@ def events_from_chrome(doc) -> List[dict]:
             continue
         track = tracks.get(ev.get("tid"), str(ev.get("tid")))
         args = dict(ev.get("args") or {})
-        args.pop("wall_s", None)
         kind = {"X": "span", "i": "instant", "C": "counter"}[ph]
         out.append({"kind": kind, "track": track, "name": ev["name"],
                     "t": ev["ts"] / 1e6,

@@ -82,6 +82,7 @@ from repro.core.cache.preloader import (PCIE_CHANNEL, SSD_CHANNEL,
                                         PrefetchEngine)
 from repro.core.cache.ssd_tier import SSDTier
 from repro.core.hw import HOST, HostHW
+from repro.obs.trace import host_span
 from repro.serving.faults import KVBlockLostError, payload_checksum
 
 #: per-tier KV storage precision maps. HBM is always fp16 — the device
@@ -482,8 +483,10 @@ class TieredKVCache:
                     "ssd.read", detail={"bid": blk.bid}) is not None:
                 self._note_ssd_failure()
                 continue
-            banks = {k: np.array(v)
-                     for k, v in self.ssd.read_layer(blk.bid).items()}
+            with host_span(self._obs_trace, "kv", "ssd_read",
+                           rid=blk.rid, bytes=int(blk.nbytes)):
+                banks = {k: np.array(v)
+                         for k, v in self.ssd.read_layer(blk.bid).items()}
             if self.faults is not None:
                 banks = self.faults.corrupt("ssd.corrupt", banks,
                                             detail={"bid": blk.bid})
@@ -535,8 +538,10 @@ class TieredKVCache:
             # backoff, folded in at the next public charge) recovers
             self.provider_faults += 1
             self._pending_fault_s += self.ssd_retry_backoff_s
-        blk.data = provider.export(blk.tok0, self.block_tokens,
-                                   scrub=scrub)
+        with host_span(self._obs_trace, "kv", "capture", rid=blk.rid,
+                       bytes=int(blk.full_nbytes)):
+            blk.data = provider.export(blk.tok0, self.block_tokens,
+                                       scrub=scrub)
         blk.real = True
         return blk.data
 
@@ -560,7 +565,10 @@ class TieredKVCache:
                 # payload is intact, so one retried import recovers
                 self.provider_faults += 1
                 self._pending_fault_s += self.ssd_retry_backoff_s
-            provider.import_(blk.tok0, Q.kv_dequantize_payload(payload))
+            with host_span(self._obs_trace, "kv", "deliver", rid=blk.rid,
+                           bytes=int(blk.full_nbytes)):
+                provider.import_(blk.tok0,
+                                 Q.kv_dequantize_payload(payload))
             blk.data = None
         else:
             blk.data = payload
@@ -678,34 +686,37 @@ class TieredKVCache:
                 and self.dram.dynamic and self._ssd_usable():
             bid = next(iter(self.dram.dynamic))
             blk = self.blocks[bid]
-            payload = self.dram.dynamic[bid]
-            if blk.real:
-                payload, prec, stored = self._quantize_for(blk, payload,
-                                                           "ssd")
-            else:
-                _, prec, stored = self._quantize_for(blk, None, "ssd")
-                if stored != blk.nbytes:
-                    payload = self._payload(prec)
-            ok, wdt = self._ssd_write(blk, payload)
-            dt += wdt
-            if not ok:
-                # write retries exhausted: the victim stays in DRAM
-                # (over-commit) rather than risking a torn flash copy —
-                # a failed demote-direction write never loses data
-                break
-            self.dram.drop(bid)
-            blk.tier = "ssd"
-            blk.data = None                    # canonical copy now on flash
-            blk.precision = prec
-            blk.nbytes = stored
-            if blk.real:
-                blk.checksum = payload_checksum(payload)
-            self.ssd_write_full_bytes += blk.full_nbytes
-            self.quant_saved_bytes += blk.full_nbytes - stored
-            self.swap_out_bytes += stored
-            dt += stored / self.hw.ssd_bw
-            self._emit("spill", blk, prev_tier="dram",
-                       cause="dram_pressure")
+            with host_span(self._obs_trace, "kv", "spill",
+                           rid=blk.rid) as span:
+                payload = self.dram.dynamic[bid]
+                if blk.real:
+                    payload, prec, stored = self._quantize_for(
+                        blk, payload, "ssd")
+                else:
+                    _, prec, stored = self._quantize_for(blk, None, "ssd")
+                    if stored != blk.nbytes:
+                        payload = self._payload(prec)
+                span.note(bytes=int(stored))
+                ok, wdt = self._ssd_write(blk, payload)
+                dt += wdt
+                if not ok:
+                    # write retries exhausted: the victim stays in DRAM
+                    # (over-commit) rather than risking a torn flash copy
+                    # — a failed demote-direction write never loses data
+                    break
+                self.dram.drop(bid)
+                blk.tier = "ssd"
+                blk.data = None                # canonical copy now on flash
+                blk.precision = prec
+                blk.nbytes = stored
+                if blk.real:
+                    blk.checksum = payload_checksum(payload)
+                self.ssd_write_full_bytes += blk.full_nbytes
+                self.quant_saved_bytes += blk.full_nbytes - stored
+                self.swap_out_bytes += stored
+                dt += stored / self.hw.ssd_bw
+                self._emit("spill", blk, prev_tier="dram",
+                           cause="dram_pressure")
         return dt
 
     def _demote(self, bid: int, *, op: str = "evict",
@@ -718,41 +729,46 @@ class TieredKVCache:
         the DRAM capacity check both price the packed bytes. Returns raw
         seconds; callers charge at the public API boundary."""
         blk = self.blocks[bid]
-        assert blk.tier == "hbm"
-        if self.prefetch is not None:
-            # an unconsumed in-flight prefetch dies with the eviction
-            self.prefetch.cancel(("kv", bid))
-        self._hbm_lru.pop(bid, None)
-        self.hbm_used -= blk.nbytes
-        payload = self._capture(blk, scrub=True)
-        payload, prec, stored = self._quantize_for(blk, payload, "dram")
-        if payload is not None:
-            blk.data = payload        # quantized dict is the host master
-            if blk.real:
-                # the bytes cross a storage boundary here: checksum the
-                # stored form so promote can verify it came back intact
-                blk.checksum = payload_checksum(payload)
-        dt = self._spill_dram_to_ssd(stored)
-        banks = payload if payload is not None else self._payload(prec)
-        nb = self.dram._nbytes(banks)
-        if self.dram.used_bytes + nb > self.dram.capacity:
-            # degraded mode (SSD quarantined or spill aborted): insert
-            # over capacity instead of letting the FIFO insert silently
-            # drop victims whose only copy now lives in DRAM
-            self.dram.dynamic[bid] = banks
-            self.dram.used_bytes += nb
-            self.dram_overcommit_max = max(
-                self.dram_overcommit_max,
-                self.dram.used_bytes - self.dram.capacity)
-        else:
-            self.dram.insert(bid, banks)
-        blk.tier = "dram"
-        blk.precision = prec
-        blk.nbytes = stored
-        self.quant_saved_bytes += blk.full_nbytes - stored
-        self.swap_out_bytes += stored
-        self._emit(op, blk, prev_tier="hbm", cause=cause)
-        return dt + stored / self.hw.pcie_bw
+        with host_span(self._obs_trace, "kv", "demote", rid=blk.rid,
+                       cause=cause) as span:
+            assert blk.tier == "hbm"
+            if self.prefetch is not None:
+                # an unconsumed in-flight prefetch dies with the eviction
+                self.prefetch.cancel(("kv", bid))
+            self._hbm_lru.pop(bid, None)
+            self.hbm_used -= blk.nbytes
+            payload = self._capture(blk, scrub=True)
+            payload, prec, stored = self._quantize_for(blk, payload,
+                                                       "dram")
+            span.note(bytes=int(stored))
+            if payload is not None:
+                blk.data = payload    # quantized dict is the host master
+                if blk.real:
+                    # the bytes cross a storage boundary here: checksum
+                    # the stored form so promote can verify it came back
+                    # intact
+                    blk.checksum = payload_checksum(payload)
+            dt = self._spill_dram_to_ssd(stored)
+            banks = payload if payload is not None else self._payload(prec)
+            nb = self.dram._nbytes(banks)
+            if self.dram.used_bytes + nb > self.dram.capacity:
+                # degraded mode (SSD quarantined or spill aborted): insert
+                # over capacity instead of letting the FIFO insert silently
+                # drop victims whose only copy now lives in DRAM
+                self.dram.dynamic[bid] = banks
+                self.dram.used_bytes += nb
+                self.dram_overcommit_max = max(
+                    self.dram_overcommit_max,
+                    self.dram.used_bytes - self.dram.capacity)
+            else:
+                self.dram.insert(bid, banks)
+            blk.tier = "dram"
+            blk.precision = prec
+            blk.nbytes = stored
+            self.quant_saved_bytes += blk.full_nbytes - stored
+            self.swap_out_bytes += stored
+            self._emit(op, blk, prev_tier="hbm", cause=cause)
+            return dt + stored / self.hw.pcie_bw
 
     def _evict_for(self, need_bytes: float, protect: Iterable[int]) -> float:
         """LRU-evict non-protected HBM blocks until ``need_bytes`` fit.
@@ -778,56 +794,59 @@ class TieredKVCache:
         the promoted block then occupies its full fp16 footprint in HBM,
         so eviction makes room for ``full_nbytes`` up front."""
         blk = self.blocks[bid]
-        dt = 0.0
-        payload = None
-        prev = blk.tier
-        stored = blk.nbytes              # packed bytes actually moved
-        stored_prec = blk.precision
-        if blk.tier == "dram":
+        with host_span(self._obs_trace, "kv", "promote", rid=blk.rid,
+                       tier=blk.tier, bytes=int(blk.nbytes),
+                       cause="demand"):
+            dt = 0.0
+            payload = None
+            prev = blk.tier
+            stored = blk.nbytes              # packed bytes actually moved
+            stored_prec = blk.precision
+            if blk.tier == "dram":
+                if blk.real:
+                    payload = blk.data if blk.data is not None \
+                        else self.dram.dynamic.get(bid)
+                    if payload is not None and self.faults is not None:
+                        corrupted = self.faults.corrupt(
+                            "dram.corrupt", payload, detail={"bid": bid})
+                        if corrupted is not payload:
+                            # a bit flipped in the DRAM master itself — the
+                            # canonical copy is what got hit, so there is
+                            # nothing clean left to retry against
+                            payload = blk.data = corrupted
+                            self.dram.dynamic[bid] = corrupted
+                    if payload is not None and blk.checksum is not None \
+                            and payload_checksum(payload) != blk.checksum:
+                        self.checksum_failures += 1
+                        self._lost(blk, "payload checksum mismatch (dram)")
+                dt += self._evict_for(blk.full_nbytes, protect)
+                self.dram.drop(bid)
+                dt += stored / self.hw.pcie_bw
+            elif blk.tier == "ssd":
+                try:
+                    banks, rdt = self._ssd_read(blk)   # real flash read,
+                except KVBlockLostError as e:          # retried + verified
+                    self._lost(blk, e.reason)
+                if blk.real:
+                    payload = banks
+                dt += rdt + self._evict_for(blk.full_nbytes, protect)
+                self.ssd.delete_layer(bid, flush_meta=False)
+                dt += stored / self.hw.ssd_bw \
+                    + stored / self.hw.pcie_bw
+            else:
+                dt += self._evict_for(blk.full_nbytes, protect)
+            blk.tier = "hbm"
+            blk.nbytes = blk.full_nbytes
+            blk.precision = self.precision["hbm"]
+            self._hbm_lru[bid] = None
+            self.hbm_used += blk.nbytes
+            self.swap_in_bytes += stored
+            self.quant_saved_bytes += blk.full_nbytes - stored
             if blk.real:
-                payload = blk.data if blk.data is not None \
-                    else self.dram.dynamic.get(bid)
-                if payload is not None and self.faults is not None:
-                    corrupted = self.faults.corrupt(
-                        "dram.corrupt", payload, detail={"bid": bid})
-                    if corrupted is not payload:
-                        # a bit flipped in the DRAM master itself — the
-                        # canonical copy is what got hit, so there is
-                        # nothing clean left to retry against
-                        payload = blk.data = corrupted
-                        self.dram.dynamic[bid] = corrupted
-                if payload is not None and blk.checksum is not None \
-                        and payload_checksum(payload) != blk.checksum:
-                    self.checksum_failures += 1
-                    self._lost(blk, "payload checksum mismatch (dram)")
-            dt += self._evict_for(blk.full_nbytes, protect)
-            self.dram.drop(bid)
-            dt += stored / self.hw.pcie_bw
-        elif blk.tier == "ssd":
-            try:
-                banks, rdt = self._ssd_read(blk)   # real flash read,
-            except KVBlockLostError as e:          # retried + verified
-                self._lost(blk, e.reason)
-            if blk.real:
-                payload = banks
-            dt += rdt + self._evict_for(blk.full_nbytes, protect)
-            self.ssd.delete_layer(bid, flush_meta=False)
-            dt += stored / self.hw.ssd_bw \
-                + stored / self.hw.pcie_bw
-        else:
-            dt += self._evict_for(blk.full_nbytes, protect)
-        blk.tier = "hbm"
-        blk.nbytes = blk.full_nbytes
-        blk.precision = self.precision["hbm"]
-        self._hbm_lru[bid] = None
-        self.hbm_used += blk.nbytes
-        self.swap_in_bytes += stored
-        self.quant_saved_bytes += blk.full_nbytes - stored
-        if blk.real:
-            self._deliver(blk, payload)
-        self._emit("promote", blk, prev_tier=prev, cause="demand",
-                   precision=stored_prec)
-        return dt
+                self._deliver(blk, payload)
+            self._emit("promote", blk, prev_tier=prev, cause="demand",
+                       precision=stored_prec)
+            return dt
 
     def _promote_async(self, bid: int, now: float) -> float:
         """Opportunistic DRAM/SSD → HBM promotion on the modeled DMA
@@ -842,55 +861,58 @@ class TieredKVCache:
         if self.hbm_used + blk.full_nbytes > \
                 self.hbm_capacity * (1.0 - self.prefetch_headroom_frac):
             return 0.0
-        not_before = 0.0
-        payload = None
-        prev = blk.tier
-        stored = blk.nbytes              # packed bytes actually moved
-        stored_prec = blk.precision
-        if blk.tier == "dram":
-            if blk.real:
-                payload = blk.data if blk.data is not None \
-                    else self.dram.dynamic.get(bid)
-                if payload is not None and blk.checksum is not None \
-                        and payload_checksum(payload) != blk.checksum:
-                    # corrupt DRAM master noticed opportunistically:
-                    # leave it for the demand promote to escalate
-                    self.checksum_failures += 1
+        with host_span(self._obs_trace, "kv", "promote", rid=blk.rid,
+                       tier=blk.tier, bytes=int(blk.nbytes),
+                       cause="prefetch"):
+            not_before = 0.0
+            payload = None
+            prev = blk.tier
+            stored = blk.nbytes              # packed bytes actually moved
+            stored_prec = blk.precision
+            if blk.tier == "dram":
+                if blk.real:
+                    payload = blk.data if blk.data is not None \
+                        else self.dram.dynamic.get(bid)
+                    if payload is not None and blk.checksum is not None \
+                            and payload_checksum(payload) != blk.checksum:
+                        # corrupt DRAM master noticed opportunistically:
+                        # leave it for the demand promote to escalate
+                        self.checksum_failures += 1
+                        self.prefetch_skips += 1
+                        return 0.0
+                self.dram.drop(bid)
+            elif blk.tier == "ssd":
+                try:                                   # single attempt: the
+                    banks, _ = self._ssd_read(blk, attempts=1)
+                except KVBlockLostError:               # opportunistic path
+                    # skips on any fault — the flash copy stays intact and
+                    # the demand promote retries with backoff
                     self.prefetch_skips += 1
                     return 0.0
-            self.dram.drop(bid)
-        elif blk.tier == "ssd":
-            try:                                   # single attempt: the
-                banks, _ = self._ssd_read(blk, attempts=1)
-            except KVBlockLostError:               # opportunistic path
-                # skips on any fault — the flash copy stays intact and
-                # the demand promote retries with backoff
-                self.prefetch_skips += 1
-                return 0.0
+                if blk.real:
+                    payload = banks
+                self.ssd.delete_layer(bid, flush_meta=False)
+                key = ("kv_ssd", bid)
+                not_before = self.prefetch.issue(SSD_CHANNEL, key, stored,
+                                                 now)
+                self.prefetch.cancel(key)      # waiters watch the PCIe leg
+            self.prefetch.issue(PCIE_CHANNEL, ("kv", bid), stored, now,
+                                not_before=not_before)
+            blk.tier = "hbm"
+            blk.nbytes = blk.full_nbytes
+            blk.precision = self.precision["hbm"]
+            self._hbm_lru[bid] = None
+            self.hbm_used += blk.nbytes
+            self.swap_in_bytes += stored
+            self.quant_saved_bytes += blk.full_nbytes - stored
             if blk.real:
-                payload = banks
-            self.ssd.delete_layer(bid, flush_meta=False)
-            key = ("kv_ssd", bid)
-            not_before = self.prefetch.issue(SSD_CHANNEL, key, stored,
-                                             now)
-            self.prefetch.cancel(key)              # waiters watch the PCIe leg
-        self.prefetch.issue(PCIE_CHANNEL, ("kv", bid), stored, now,
-                            not_before=not_before)
-        blk.tier = "hbm"
-        blk.nbytes = blk.full_nbytes
-        blk.precision = self.precision["hbm"]
-        self._hbm_lru[bid] = None
-        self.hbm_used += blk.nbytes
-        self.swap_in_bytes += stored
-        self.quant_saved_bytes += blk.full_nbytes - stored
-        if blk.real:
-            # the host→device copy lands now; only its *arrival time* is
-            # modeled asynchronously (ensure_resident charges the
-            # residual stall of the in-flight transfer)
-            self._deliver(blk, payload)
-        self._emit("promote", blk, prev_tier=prev, cause="prefetch",
-                   precision=stored_prec)
-        return stored
+                # the host→device copy lands now; only its *arrival time* is
+                # modeled asynchronously (ensure_resident charges the
+                # residual stall of the in-flight transfer)
+                self._deliver(blk, payload)
+            self._emit("promote", blk, prev_tier=prev, cause="prefetch",
+                       precision=stored_prec)
+            return stored
 
     def _new_block(self, rid: int, protect: Iterable[int]) -> float:
         dt = self._evict_for(self.block_bytes, protect)

@@ -64,6 +64,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.carbon import CarbonIntensityTrace
+from repro.obs.trace import host_span
 # payload_checksum moved to faults.py (shared with TieredKVCache's
 # demote/promote verification); re-exported here for back-compat
 from repro.serving.faults import payload_checksum  # noqa: F401
@@ -222,24 +223,28 @@ class PrefixCache:
         """Match and take refs on the hit path for ``rid``: each path
         node gains a locker and its blocks are pinned against HBM
         eviction until :meth:`release`."""
-        assert rid not in self._locked, f"rid {rid} already holds locks"
-        m = self.match(tokens)
-        self._locked[rid] = list(m.nodes)
-        for node in m.nodes:
-            if not node.lockers:
-                self.kv.pin(node.rid)
-            node.holders.add(rid)
-            node.lockers.add(rid)
-            node.last_used = max(node.last_used, now)
-        self.lookups += 1
-        self.lookup_tokens_total += len(tokens)
-        if m.hit_tokens:
-            self.hit_requests += 1
-            self.hit_tokens_total += m.hit_tokens
-        self._obs("hit" if m.hit_tokens else "miss", rid=rid,
-                  hit_tokens=m.hit_tokens, lookup_tokens=len(tokens),
-                  path_nodes=len(m.nodes))
-        return m
+        with host_span(self._obs_trace, "prefix", "lock", rid=rid,
+                       lookup_tokens=len(tokens)) as span:
+            assert rid not in self._locked, \
+                f"rid {rid} already holds locks"
+            m = self.match(tokens)
+            self._locked[rid] = list(m.nodes)
+            for node in m.nodes:
+                if not node.lockers:
+                    self.kv.pin(node.rid)
+                node.holders.add(rid)
+                node.lockers.add(rid)
+                node.last_used = max(node.last_used, now)
+            self.lookups += 1
+            self.lookup_tokens_total += len(tokens)
+            if m.hit_tokens:
+                self.hit_requests += 1
+                self.hit_tokens_total += m.hit_tokens
+            self._obs("hit" if m.hit_tokens else "miss", rid=rid,
+                      hit_tokens=m.hit_tokens, lookup_tokens=len(tokens),
+                      path_nodes=len(m.nodes))
+            span.note(hit_tokens=m.hit_tokens)
+            return m
 
     def node_rids(self, rid: int) -> List[int]:
         """KV rids of the nodes ``rid`` currently locks (root→leaf
@@ -338,59 +343,64 @@ class PrefixCache:
         admitted with — its own KV blocks cover ``[prefix_hit, ...)``.
         New nodes are locked for ``rid`` (the request keeps reading the
         donated blocks until it finishes). Returns donated tokens."""
-        if not self._should_cache(now):
-            self.insert_skips_carbon += 1
-            self._obs("insert_skip_carbon", rid=rid)
-            return 0
-        qb = self._query_blocks(tokens)
-        path, matched, partial = self._walk(qb)
-        if partial:
-            # divergence inside an edge: fork at the matched boundary so
-            # the shared head becomes matchable on its own
-            child = path[-1].children[qb[matched]] if path \
-                else self.root.children[qb[matched]]
-            self._split(child, partial)
-            path.append(child)
-            matched += partial
-        donate_from = matched * self.block_tokens
-        # the tree may have grown past our admission-time hit (another
-        # request inserted the same prefix first); our duplicate blocks
-        # for [prefix_hit, donate_from) stay owned by the request
-        if donate_from < prefix_hit or matched >= len(qb):
-            return 0
-        nblocks = len(qb) - matched
-        start_block = (donate_from - prefix_hit) // self.block_tokens
-        node = RadixNode(rid=self._next_node_rid, blocks=qb[matched:],
-                         parent=path[-1] if path else self.root,
-                         last_used=now)
-        self._next_node_rid -= 1
-        # real KV residency: capture host copies of the donated blocks'
-        # actual tensor bytes (device_get from the donor's cache) before
-        # ownership moves — these are what a later hit restores, and what
-        # save() persists to flash. With quantized tiers the host master
-        # is encoded at the (possibly carbon-chosen) insert precision.
-        prec = self._pick_precision(now)
-        self.kv.materialize(rid, start_block, nblocks, precision=prec)
-        if prec == "int8":
-            self.inserts_int8 += 1
-        elif prec == "int4":
-            self.inserts_int4 += 1
-        self.kv.adopt_blocks(rid, node.rid, nblocks,
-                             start_block=start_block)
-        node.parent.children[node.blocks[0]] = node
-        self.nodes += 1
-        ntok = node.ntokens
-        self.cached_tokens += ntok
-        self.inserted_tokens += ntok
-        # the donor keeps reading these blocks: hold + pin immediately
-        node.holders.add(rid)
-        node.lockers.add(rid)
-        self._locked.setdefault(rid, []).append(node)
-        self.kv.pin(node.rid)
-        self._obs("insert", rid=rid, node_rid=node.rid, tokens=ntok,
-                  precision=prec)
-        self._reclaim(now)
-        return ntok
+        with host_span(self._obs_trace, "prefix", "insert",
+                       rid=rid) as span:
+            if not self._should_cache(now):
+                self.insert_skips_carbon += 1
+                self._obs("insert_skip_carbon", rid=rid)
+                return 0
+            qb = self._query_blocks(tokens)
+            path, matched, partial = self._walk(qb)
+            if partial:
+                # divergence inside an edge: fork at the matched boundary
+                # so the shared head becomes matchable on its own
+                child = path[-1].children[qb[matched]] if path \
+                    else self.root.children[qb[matched]]
+                self._split(child, partial)
+                path.append(child)
+                matched += partial
+            donate_from = matched * self.block_tokens
+            # the tree may have grown past our admission-time hit
+            # (another request inserted the same prefix first); our
+            # duplicate blocks for [prefix_hit, donate_from) stay owned by
+            # the request
+            if donate_from < prefix_hit or matched >= len(qb):
+                return 0
+            nblocks = len(qb) - matched
+            start_block = (donate_from - prefix_hit) // self.block_tokens
+            node = RadixNode(rid=self._next_node_rid, blocks=qb[matched:],
+                             parent=path[-1] if path else self.root,
+                             last_used=now)
+            self._next_node_rid -= 1
+            # real KV residency: capture host copies of the donated
+            # blocks' actual tensor bytes (device_get from the donor's
+            # cache) before ownership moves — these are what a later hit
+            # restores, and what save() persists to flash. With quantized
+            # tiers the host master is encoded at the (possibly
+            # carbon-chosen) insert precision.
+            prec = self._pick_precision(now)
+            self.kv.materialize(rid, start_block, nblocks, precision=prec)
+            if prec == "int8":
+                self.inserts_int8 += 1
+            elif prec == "int4":
+                self.inserts_int4 += 1
+            self.kv.adopt_blocks(rid, node.rid, nblocks,
+                                 start_block=start_block)
+            node.parent.children[node.blocks[0]] = node
+            self.nodes += 1
+            ntok = node.ntokens
+            self.cached_tokens += ntok
+            self.inserted_tokens += ntok
+            # the donor keeps reading these blocks: hold + pin immediately
+            node.holders.add(rid)
+            node.lockers.add(rid)
+            self._locked.setdefault(rid, []).append(node)
+            self.kv.pin(node.rid)
+            self._obs("insert", rid=rid, node_rid=node.rid, tokens=ntok,
+                      precision=prec)
+            self._reclaim(now)
+            span.note(tokens=ntok)
+            return ntok
 
     # ------------------------------------------------------------------
     def invalidate(self, node_rid: int, *, now: float = 0.0) -> int:

@@ -91,6 +91,12 @@ class ServingRequest:
     admitted_s: Optional[float] = None
     first_token_s: Optional[float] = None
     finish_s: Optional[float] = None
+    # host clock (time.perf_counter, s), set by the scheduler whether or
+    # not a recorder is attached: first released into the queue, first
+    # taken from it for admission, first token back on the host
+    host_released_s: Optional[float] = None
+    host_admitted_s: Optional[float] = None
+    host_first_token_s: Optional[float] = None
     generated: int = 0
     prompt_done: int = 0                      # prefill tokens completed
     preemptions: int = 0

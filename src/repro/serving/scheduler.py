@@ -17,7 +17,9 @@ Units and clock semantics: every cost — prefill chunks, batched decode,
 KV swaps, idle gaps — lands on the engine's modeled transfer clock in
 **seconds** (`M2CacheEngine.clock`); request timestamps (`arrival_s`,
 `admitted_s`, `first_token_s`, `finish_s`) are rebased to the run's clock
-origin, so latencies are plain differences. Carbon is integrated
+origin, so latencies are plain differences. An engine whose clock does
+not jump when charged an idle wait (a host clock behind an adapter) is
+waited for in real time instead. Carbon is integrated
 step-by-step by a :class:`~repro.core.carbon.CarbonAccountant` in
 **gCO2**, pricing each iteration's energy (J) at the grid intensity of
 that moment, which is what makes carbon-aware deferral visible in
@@ -30,12 +32,14 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
 
 from repro.core import carbon as carbon_mod
+from repro.obs.trace import host_span
 from repro.serving.faults import KVBlockLostError
 from repro.serving.kv_cache import TieredKVCache
 from repro.serving.policy import FCFSPolicy, SchedulingPolicy
@@ -281,7 +285,11 @@ class ContinuousBatchScheduler:
     tier transition in the replacement-policy-lab replay format;
     ``metrics`` (a :class:`repro.obs.MetricsRegistry`) accumulates
     serving counters/gauges/histograms, with ``snapshotter`` ticked
-    once per scheduler iteration on the modeled clock.
+    once per scheduler iteration on the modeled clock. With ``trace``
+    the scheduler, engine, prefix cache and KV tiers also record
+    host-clock spans (``TraceRecorder.host_span``); every request gets
+    host stamps (``host_released_s``, ``host_admitted_s``,
+    ``host_first_token_s``) with or without it.
     """
 
     def __init__(self, engine, kv: Optional[TieredKVCache] = None, *,
@@ -373,8 +381,16 @@ class ContinuousBatchScheduler:
         # quarantine re-probe timing (kv_cache._maybe_reprobe) runs on
         # the same modeled clock; harmless without faults
         self.kv.set_clock(clk)
+        # the engine records its host spans to whichever scheduler
+        # drives it now (None detaches an earlier recorder)
+        attach = getattr(engine, "attach_obs", None)
+        if attach is not None:
+            attach(trace)
         if trace is not None:
-            trace.set_default_clock(clk)
+            # weakly: a recorder that outlives this scheduler must not
+            # keep its engine (weights, KV) alive
+            sched = weakref.ref(self)
+            trace.set_default_clock(lambda: sched().engine.clock)
             pf = getattr(engine, "prefetch", None)
             if pf is not None:
                 pf.attach_trace(trace)
@@ -534,6 +550,8 @@ class ContinuousBatchScheduler:
     def _admit(self, req: ServingRequest, active: List[ServingRequest]):
         """Admit (or resume) one request into the active set."""
         eng, kv = self.engine, self.kv
+        if req.host_admitted_s is None:
+            req.host_admitted_s = time.perf_counter()
         protect = [r.rid for r in active] + [req.rid]
         if req.state is RequestState.PREEMPTED:
             # resume: KV swaps back in (or, if prefetched ahead, pays only
@@ -887,6 +905,7 @@ class ContinuousBatchScheduler:
             self._iter_bill = {} if self.ledger is not None else None
             now = eng.clock - clock_start
             while i < len(pending) and pending[i].arrival_s <= now:
+                pending[i].host_released_s = time.perf_counter()
                 waiting.append(pending[i])
                 i += 1
             if not active and not any(self.policy.may_start(r, now)
@@ -904,7 +923,13 @@ class ContinuousBatchScheduler:
                         "without a holdoff_until time")
                 dt = max(min(targets) - now, 1e-9)
                 t_idle0 = eng.clock
-                eng.advance_clock(dt)
+                with host_span(self.trace, "sched", "idle"):
+                    eng.advance_clock(dt)
+                    # a clock that does not jump (the host's, behind an
+                    # adapter) is waited for, not polled
+                    behind = t_idle0 + dt - eng.clock
+                    if behind > 0.0:
+                        time.sleep(behind)
                 g_idle = accountant.charge(now, dt, 0.0, self._dram_gb(),
                                            active=False)
                 if self.ledger is not None:
@@ -919,193 +944,204 @@ class ContinuousBatchScheduler:
                     self.snapshotter.tick(eng.clock)
                 self._persist_tick()
                 continue
-            # admit in policy order up to max_batch; stop when the KV
-            # budget says no (carbon-held requests are skipped, not
-            # blocking the ones behind them). A prefix-cache lookup runs
-            # *before* the budget check: hit tokens live in shared radix
-            # blocks, so only the suffix needs blocks of the request's
-            # own
-            t_adm0 = eng.clock
-            r_adm0 = self._retrans_s()
-            for req in self.policy.admission_order(waiting, now):
-                if len(active) >= self.max_batch:
-                    break
-                if not self.policy.may_start(req, now):
-                    continue
-                need = max(req.total_tokens, 1)
-                if self.prefix is not None and req.prompt is not None:
-                    if req.state is RequestState.PREEMPTED:
-                        need = req.own_kv_tokens
+            with host_span(self.trace, "sched", "iteration"):
+                # admit in policy order up to max_batch; stop when the KV
+                # budget says no (carbon-held requests are skipped, not
+                # blocking the ones behind them). A prefix-cache lookup runs
+                # *before* the budget check: hit tokens live in shared radix
+                # blocks, so only the suffix needs blocks of the request's
+                # own
+                t_adm0 = eng.clock
+                r_adm0 = self._retrans_s()
+                with host_span(self.trace, "sched", "admit",
+                               waiting=len(waiting)) as adm:
+                    for req in self.policy.admission_order(waiting, now):
+                        if len(active) >= self.max_batch:
+                            break
+                        if not self.policy.may_start(req, now):
+                            continue
+                        need = max(req.total_tokens, 1)
+                        if self.prefix is not None \
+                                and req.prompt is not None:
+                            if req.state is RequestState.PREEMPTED:
+                                need = req.own_kv_tokens
+                            else:
+                                need = max(req.total_tokens
+                                           - self.prefix.match(
+                                               req.true_prompt()
+                                           ).hit_tokens, 1)
+                        if not kv.can_admit(
+                                need, [r.rid for r in active]) and active:
+                            break
+                        waiting.remove(req)
+                        try:
+                            self._admit(req, active)
+                        except KVBlockLostError as e:
+                            # a block needed for residency is unrecoverably
+                            # gone: route to recovery (re-queue /
+                            # re-prefill / clean failure) and keep serving
+                            # everyone else
+                            recoveries += self._on_block_lost(
+                                e, req, waiting, failed)
+                    adm.note(active=len(active))
+                # every clock advance inside admission is a KV residency
+                # charge (ensure_resident / restores), net of DMA retransfer
+                self._bill_region("kv_stall", t_adm0, r_adm0)
+                # one prefill chunk per prefilling request, then resolve KV
+                # pressure (possibly preempting mid-prefill), then decode
+                comp, chunks, pf_stall, pf_overlap, pf_disp, pf_deltas = \
+                    self._prefill_step(active)
+                iter_compute += comp
+                prefill_chunks += chunks
+                if chunks:
+                    prefill_steps += 1
+                prefill_dispatches += pf_disp
+                stall_s += pf_stall
+                overlapped += pf_overlap
+                # keep refs to this iteration's prefillers before preemption
+                # can move them back to waiting — carbon attribution below
+                # still charges them for the work they did this step
+                by_rid = {r.rid: r for r in active}
+                n, mid = self._preempt(active, waiting)
+                preemptions += n
+                mid_prefill_preemptions += mid
+                running = [r for r in active
+                           if r.state is RequestState.RUNNING]
+                finished_now: List[ServingRequest] = []
+                # issue next step's predicted KV promotions before decoding so
+                # the transfers overlap this step's compute on the DMA clock
+                self._prefetch_ahead(waiting, eng.clock - clock_start)
+                if running:
+                    t_dec0 = eng.clock
+                    r_dec0 = self._retrans_s()
+                    if eng.manager is not None:
+                        eng.manager.dispatch_log.clear()
+                    rep = eng.decode_step([r.session for r in running])
+                    t_tok = time.perf_counter()    # tokens are on the host
+                    if wall_first_token is None:
+                        wall_first_token = t_tok - wall0
+                        first_step_tokens = len(running)
+                    dec_disp = self._drain_dispatches("decode")
+                    dec_dt = eng.clock - t_dec0
+                    self._bill_step(
+                        "decode", dec_dt,
+                        min(max(self._retrans_s() - r_dec0, 0.0), dec_dt),
+                        rep.stall_s, dec_disp, len(running))
+                    iter_compute += rep.compute_s
+                    decode_steps += 1
+                    jit_dispatches += rep.jit_dispatches
+                    stall_s += rep.stall_s
+                    overlapped += rep.overlapped_bytes
+                    for r in running:
+                        kv.touch(r.rid)
+                        dt_app = kv.append_token(r.rid,
+                                                 [x.rid for x in active])
+                        eng.advance_clock(dt_app)
+                        self._bill_time("kv_stall", dt_app)
+                        r.generated += 1
+                        if r.first_token_s is None:
+                            r.first_token_s = eng.clock - clock_start
+                            r.host_first_token_s = t_tok
+                            if self.trace is not None:
+                                self.trace.instant(f"req:{r.rid}",
+                                                   "first_token",
+                                                   ttft_s=r.ttft_s)
+                    if self.trace is not None:
+                        self.trace.span("sched", "decode_step", t_dec0,
+                                        eng.clock, batch=len(running))
+                    if self._m is not None:
+                        self._m["tokens"].inc(len(running))
+                    for r in running:
+                        if r.done:
+                            r.state = RequestState.FINISHED
+                            r.finish_s = eng.clock - clock_start
+                            if self.prefix is not None:
+                                self.prefix.release(
+                                    r.rid, now=eng.clock - clock_start)
+                            kv.free(r.rid)
+                            finished.append(r)
+                            active.remove(r)
+                            finished_now.append(r)
+                slice_g = accountant.charge(iter_clock0 - clock_start,
+                                            eng.clock - iter_clock0,
+                                            iter_compute, self._dram_gb())
+                # split this iteration's carbon across the requests that did
+                # work in it, proportional to tokens processed (prefill
+                # chunks + one decode token per running request)
+                iter_work = [(by_rid[rid], "prefill", d)
+                             for rid, d in pf_deltas.items()] \
+                    + [(r, "decode", 1) for r in running]
+                tot = sum(w for _, _, w in iter_work)
+                if slice_g > 0.0 and tot > 0:
+                    for r, phase, w in iter_work:
+                        g = slice_g * w / tot
+                        r.gco2_g += g
+                        if phase == "prefill":
+                            r.gco2_prefill_g += g
+                            if r.recoveries:
+                                # every post-recovery prefill slice is redo
+                                # work a fault destroyed — the reliability
+                                # tax, reported as gco2_recovery_total
+                                r.gco2_recovery_g += g
+                        else:
+                            r.gco2_decode_g += g
+                    if self._m is not None:
+                        self._m["gco2"].inc(slice_g)
+                if self.ledger is not None and slice_g > 0.0:
+                    # operational carbon follows time: split the slice across
+                    # this iteration's billed categories by time share
+                    bill_tot = sum(self._iter_bill.values())
+                    if bill_tot > 0.0:
+                        for cat, dtc in self._iter_bill.items():
+                            self.ledger.bill_g(cat, slice_g * dtc / bill_tot)
                     else:
-                        need = max(req.total_tokens - self.prefix.match(
-                            req.true_prompt()).hit_tokens, 1)
-                if not kv.can_admit(need,
-                                    [r.rid for r in active]) and active:
-                    break
-                waiting.remove(req)
-                try:
-                    self._admit(req, active)
-                except KVBlockLostError as e:
-                    # a block needed for residency is unrecoverably gone:
-                    # route to recovery (re-queue / re-prefill / clean
-                    # failure) and keep serving everyone else
-                    recoveries += self._on_block_lost(e, req, waiting,
-                                                      failed)
-            # every clock advance inside admission is a KV residency
-            # charge (ensure_resident / restores), net of DMA retransfer
-            self._bill_region("kv_stall", t_adm0, r_adm0)
-            # one prefill chunk per prefilling request, then resolve KV
-            # pressure (possibly preempting mid-prefill), then decode
-            comp, chunks, pf_stall, pf_overlap, pf_disp, pf_deltas = \
-                self._prefill_step(active)
-            iter_compute += comp
-            prefill_chunks += chunks
-            if chunks:
-                prefill_steps += 1
-            prefill_dispatches += pf_disp
-            stall_s += pf_stall
-            overlapped += pf_overlap
-            # keep refs to this iteration's prefillers before preemption
-            # can move them back to waiting — carbon attribution below
-            # still charges them for the work they did this step
-            by_rid = {r.rid: r for r in active}
-            n, mid = self._preempt(active, waiting)
-            preemptions += n
-            mid_prefill_preemptions += mid
-            running = [r for r in active if r.state is RequestState.RUNNING]
-            finished_now: List[ServingRequest] = []
-            # issue next step's predicted KV promotions before decoding so
-            # the transfers overlap this step's compute on the DMA clock
-            self._prefetch_ahead(waiting, eng.clock - clock_start)
-            if running:
-                t_dec0 = eng.clock
-                r_dec0 = self._retrans_s()
-                if eng.manager is not None:
-                    eng.manager.dispatch_log.clear()
-                rep = eng.decode_step([r.session for r in running])
-                if wall_first_token is None:
-                    wall_first_token = time.perf_counter() - wall0
-                    first_step_tokens = len(running)
-                dec_disp = self._drain_dispatches("decode")
-                dec_dt = eng.clock - t_dec0
-                self._bill_step(
-                    "decode", dec_dt,
-                    min(max(self._retrans_s() - r_dec0, 0.0), dec_dt),
-                    rep.stall_s, dec_disp, len(running))
-                iter_compute += rep.compute_s
-                decode_steps += 1
-                jit_dispatches += rep.jit_dispatches
-                stall_s += rep.stall_s
-                overlapped += rep.overlapped_bytes
-                for r in running:
-                    kv.touch(r.rid)
-                    dt_app = kv.append_token(r.rid,
-                                             [x.rid for x in active])
-                    eng.advance_clock(dt_app)
-                    self._bill_time("kv_stall", dt_app)
-                    r.generated += 1
-                    if r.first_token_s is None:
-                        r.first_token_s = eng.clock - clock_start
-                        if self.trace is not None:
-                            self.trace.instant(f"req:{r.rid}",
-                                               "first_token",
-                                               ttft_s=r.ttft_s)
+                        self.ledger.bill_g("other", slice_g)
+                # finish events fire *after* carbon attribution so the
+                # instant's gco2_g carries the request's full footprint
+                for r in finished_now:
+                    if self.trace is not None:
+                        self._obs_phase_end(r, generated=r.generated)
+                        self.trace.instant(f"req:{r.rid}", "finish",
+                                           latency_s=r.latency_s,
+                                           gco2_g=r.gco2_g)
+                    if self._m is not None:
+                        self._m["finished"].inc()
+                        if r.slo is not None and not r.slo_met():
+                            self._m["slo_violations"].inc()
+                        self._m["ttft"].observe(r.ttft_s)
+                        self._m["latency"].observe(r.latency_s)
+                        self._m["tpot"].observe(r.tpot_s)
                 if self.trace is not None:
-                    self.trace.span("sched", "decode_step", t_dec0,
-                                    eng.clock, batch=len(running))
+                    self.trace.counter("sched", "queue", active=len(active),
+                                       waiting=len(waiting))
+                    self.trace.counter("kv", "kv_bytes",
+                                       hbm=kv.hbm_used,
+                                       dram=kv.dram.used_bytes)
                 if self._m is not None:
-                    self._m["tokens"].inc(len(running))
-                for r in running:
-                    if r.done:
-                        r.state = RequestState.FINISHED
-                        r.finish_s = eng.clock - clock_start
-                        if self.prefix is not None:
-                            self.prefix.release(
-                                r.rid, now=eng.clock - clock_start)
-                        kv.free(r.rid)
-                        finished.append(r)
-                        active.remove(r)
-                        finished_now.append(r)
-            slice_g = accountant.charge(iter_clock0 - clock_start,
-                                        eng.clock - iter_clock0,
-                                        iter_compute, self._dram_gb())
-            # split this iteration's carbon across the requests that did
-            # work in it, proportional to tokens processed (prefill
-            # chunks + one decode token per running request)
-            iter_work = [(by_rid[rid], "prefill", d)
-                         for rid, d in pf_deltas.items()] \
-                + [(r, "decode", 1) for r in running]
-            tot = sum(w for _, _, w in iter_work)
-            if slice_g > 0.0 and tot > 0:
-                for r, phase, w in iter_work:
-                    g = slice_g * w / tot
-                    r.gco2_g += g
-                    if phase == "prefill":
-                        r.gco2_prefill_g += g
-                        if r.recoveries:
-                            # every post-recovery prefill slice is redo
-                            # work a fault destroyed — the reliability
-                            # tax, reported as gco2_recovery_total
-                            r.gco2_recovery_g += g
-                    else:
-                        r.gco2_decode_g += g
-                if self._m is not None:
-                    self._m["gco2"].inc(slice_g)
-            if self.ledger is not None and slice_g > 0.0:
-                # operational carbon follows time: split the slice across
-                # this iteration's billed categories by time share
-                bill_tot = sum(self._iter_bill.values())
-                if bill_tot > 0.0:
-                    for cat, dtc in self._iter_bill.items():
-                        self.ledger.bill_g(cat, slice_g * dtc / bill_tot)
-                else:
-                    self.ledger.bill_g("other", slice_g)
-            # finish events fire *after* carbon attribution so the
-            # instant's gco2_g carries the request's full footprint
-            for r in finished_now:
-                if self.trace is not None:
-                    self._obs_phase_end(r, generated=r.generated)
-                    self.trace.instant(f"req:{r.rid}", "finish",
-                                       latency_s=r.latency_s,
-                                       gco2_g=r.gco2_g)
-                if self._m is not None:
-                    self._m["finished"].inc()
-                    if r.slo is not None and not r.slo_met():
-                        self._m["slo_violations"].inc()
-                    self._m["ttft"].observe(r.ttft_s)
-                    self._m["latency"].observe(r.latency_s)
-                    self._m["tpot"].observe(r.tpot_s)
-            if self.trace is not None:
-                self.trace.counter("sched", "queue", active=len(active),
-                                   waiting=len(waiting))
-                self.trace.counter("kv", "kv_bytes",
-                                   hbm=kv.hbm_used,
-                                   dram=kv.dram.used_bytes)
-            if self._m is not None:
-                self._m["active"].set(len(active))
-                self._m["waiting"].set(len(waiting))
-                self._m["hbm_kv"].set(kv.hbm_used)
-                self._m["ssd_quarantined"].set(
-                    1.0 if kv.ssd_quarantined else 0.0)
-                self._m["dram_overcommit"].set(
-                    max(kv.dram.used_bytes - kv.dram.capacity, 0))
-                if self.prefix is not None:
-                    pcur = self.prefix.stats()
-                    self._m["prefix_hit_rate"].set(
-                        pcur["prefix_hit_tokens"]
-                        / max(pcur["prefix_lookup_tokens"], 1))
-                if self.trace is not None and \
-                        self.trace.dropped_events > self._trace_drops_seen:
-                    self._m["trace_drops"].inc(
-                        self.trace.dropped_events - self._trace_drops_seen)
-                    self._trace_drops_seen = self.trace.dropped_events
-            if self.health is not None:
-                self.health.evaluate(eng.clock - clock_start)
-            if self.ledger is not None and self.trace is not None:
-                self.ledger.emit(self.trace, eng.clock)
-            if self.snapshotter is not None:
-                self.snapshotter.tick(eng.clock)
-            self._persist_tick()
+                    self._m["active"].set(len(active))
+                    self._m["waiting"].set(len(waiting))
+                    self._m["hbm_kv"].set(kv.hbm_used)
+                    self._m["ssd_quarantined"].set(
+                        1.0 if kv.ssd_quarantined else 0.0)
+                    self._m["dram_overcommit"].set(
+                        max(kv.dram.used_bytes - kv.dram.capacity, 0))
+                    if self.prefix is not None:
+                        pcur = self.prefix.stats()
+                        self._m["prefix_hit_rate"].set(
+                            pcur["prefix_hit_tokens"]
+                            / max(pcur["prefix_lookup_tokens"], 1))
+                    if self.trace is not None and \
+                            self.trace.dropped_events > self._trace_drops_seen:
+                        self._m["trace_drops"].inc(
+                            self.trace.dropped_events - self._trace_drops_seen)
+                        self._trace_drops_seen = self.trace.dropped_events
+                if self.health is not None:
+                    self.health.evaluate(eng.clock - clock_start)
+                if self.ledger is not None and self.trace is not None:
+                    self.ledger.emit(self.trace, eng.clock)
+                if self.snapshotter is not None:
+                    self.snapshotter.tick(eng.clock)
+                self._persist_tick()
 
         span = eng.clock - clock_start
         if horizon_s is not None and horizon_s > span:

@@ -16,7 +16,12 @@ Acceptance properties:
   trace alone (matching the report), attributes each iteration's gCO2
   to the requests that did the work, and never perturbs the modeled
   clock (tracing on/off spans are identical; real-tiny tokens are
-  byte-identical).
+  byte-identical);
+* host-clock spans nest with their parent and rid, share the ring's
+  drop accounting, export as their own process, cover every served
+  layer of a real-tiny run, and the ``engine.decode.*`` children lie
+  inside their step; every request's host stamps are ordered; the
+  served programs keep the names the device-trace reductions match.
 """
 import json
 import pathlib
@@ -27,7 +32,9 @@ import pytest
 
 from repro.core.engine import M2CacheEngine
 from repro.obs import (BlockAccessEvent, BlockTraceCollector, MetricsRegistry,
-                       PeriodicSnapshotter, TraceRecorder, read_block_trace)
+                       PeriodicSnapshotter, TraceRecorder, events_from_chrome,
+                       events_from_recorder, read_block_trace)
+from repro.obs.trace import HOST, NO_SPAN, host_span
 from repro.serving import (ContinuousBatchScheduler, PrefixCache,
                            requests_from_trace)
 from repro.serving.kv_cache import TieredKVCache
@@ -102,25 +109,97 @@ def test_chrome_trace_json_valid(tmp_path):
     tr.span("req:0", "prefill", 1.0, 2.5, tokens=16)
     tr.instant("sched", "admit", t=1.0, rid=0)
     tr.counter("kv", "kv_bytes", t=2.0, hbm=1024, dram=0)
+    with tr.host_span("sched", "admit", rid=0, waiting=1):
+        pass
     path = tmp_path / "t.trace.json"
     tr.export_chrome(str(path))
     doc = json.loads(path.read_text())       # valid JSON round-trip
     evs = doc["traceEvents"]
-    by_ph = {e["ph"]: e for e in evs}
+    host_pid = doc["otherData"]["host_pid"]
+    by_ph = {e["ph"]: e for e in evs if e["pid"] != host_pid}
     assert set(by_ph) == {"M", "X", "i", "C"}
     # complete span: µs timestamps + duration, args preserved
     x = by_ph["X"]
     assert x["ts"] == pytest.approx(1.0e6)
     assert x["dur"] == pytest.approx(1.5e6)
-    assert x["args"]["tokens"] == 16 and "wall_s" in x["args"]
-    # instant scope + counter series (no wall_s polluting the plot)
+    assert x["args"] == {"tokens": 16}
+    # the host span rides in its own process, on the host clock
+    (h,) = [e for e in evs if e["pid"] == host_pid and e["ph"] == "X"]
+    assert h["name"] == "admit" and h["args"] == {"rid": 0, "waiting": 1}
+    assert h["ts"] > 0 and h["dur"] >= 0
+    # instant scope + counter series (args are the series alone)
     assert by_ph["i"]["s"] == "t"
     assert by_ph["C"]["args"] == {"hbm": 1024.0, "dram": 0.0}
     # every referenced tid has thread_name metadata
     named = {e["tid"] for e in evs if e["ph"] == "M"}
     assert {e["tid"] for e in evs} <= named
-    tracks = {e["args"]["name"] for e in evs if e["ph"] == "M"}
-    assert tracks == {"req:0", "sched", "kv"}
+    tracks = {(e["pid"], e["args"]["name"]) for e in evs
+              if e["name"] == "thread_name"}
+    assert tracks == {(1, "req:0"), (1, "sched"), (1, "kv"),
+                      (host_pid, "sched")}
+
+
+def test_host_spans_nest_with_parent_and_rid():
+    tr = TraceRecorder()
+    with tr.host_span("engine", "decode_step", rows=2) as outer:
+        with tr.host_span("engine", "decode.dispatch", rid=7) as inner:
+            inner.note(bytes=64)
+        assert tr.open_spans() == 1
+    assert tr.open_spans() == 0
+    inner_ev, outer_ev = tr.events()     # closes emit in end order
+    assert inner_ev.clock == outer_ev.clock == HOST
+    assert inner_ev.args == {"rid": 7, "bytes": 64,
+                             "parent": "engine.decode_step"}
+    assert outer_ev.args == {"rows": 2} and outer is not inner
+    # host-clock nesting: the child lies inside its parent
+    assert outer_ev.t <= inner_ev.t
+    assert inner_ev.t + inner_ev.dur <= outer_ev.t + outer_ev.dur
+    # a span closed by an exception still leaves the stack clean
+    with pytest.raises(KeyError):
+        with tr.host_span("kv", "demote"):
+            raise KeyError
+    assert tr.open_spans() == 0 and tr.events()[-1].name == "demote"
+    # no recorder: one shared no-op, nothing recorded anywhere
+    with host_span(None, "kv", "demote", rid=1) as off:
+        off.note(bytes=1)
+    assert off is NO_SPAN
+
+
+def test_ring_overflow_counts_host_spans():
+    tr = TraceRecorder(capacity=4)
+    for i in range(3):
+        tr.instant("x", f"e{i}", t=float(i))
+        with tr.host_span("sched", f"s{i}"):
+            pass
+    s = tr.stats()
+    assert s["trace_total_events"] == 6
+    assert s["trace_dropped_events"] == 2 and s["trace_events"] == 4
+    assert [e.name for e in tr.events()] == ["e1", "s1", "e2", "s2"]
+
+
+def test_export_puts_host_spans_in_own_process():
+    tr = TraceRecorder()
+    tr.span("sched", "decode_step", 1.0, 2.0, batch=2)
+    with tr.host_span("sched", "iteration"):
+        with tr.host_span("engine", "decode_step", rows=2):
+            pass
+    doc = tr.to_chrome()
+    host_pid = doc["otherData"]["host_pid"]
+    procs = {e["pid"]: e["args"]["name"] for e in doc["traceEvents"]
+             if e["name"] == "process_name"}
+    assert procs == {1: "modeled clock", host_pid: "host clock"}
+    host = [e for e in doc["traceEvents"]
+            if e["pid"] == host_pid and e["ph"] == "X"]
+    assert sorted(e["name"] for e in host) == ["decode_step", "iteration"]
+    # a track name shared by both clocks is two threads, never one
+    sched_tids = {e["tid"] for e in doc["traceEvents"]
+                  if e["name"] == "thread_name"
+                  and e["args"]["name"] == "sched"}
+    assert len(sched_tids) == 2
+    # the modeled-clock readers never see a host span
+    for evs in (events_from_recorder(tr), events_from_chrome(doc)):
+        assert [(e["track"], e["name"]) for e in evs] == \
+            [("sched", "decode_step")]
 
 
 # ---------------------------------------------------------------------------
@@ -489,3 +568,131 @@ def test_real_tiny_tokens_identical_tracing_on_off(tmp_path, tiny_model):
     assert toks_on == toks_off               # byte-identical generation
     assert rep_on.modeled_span_s == rep_off.modeled_span_s
     assert rep_on.preemptions == rep_off.preemptions > 0
+
+
+# ---------------------------------------------------------------------------
+# real-tiny: host spans on the served path
+
+
+@pytest.fixture(scope="module")
+def served_with_and_without_recorder(tmp_path_factory, tiny_model):
+    """One real-tiny run of a shared-prefix trace under tight KV budgets
+    (preemption, DRAM and SSD demotion, prefix restores, an idle gap),
+    once with a recorder attached and once without."""
+    cfg, params = tiny_model
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, cfg.vocab_size, 32).tolist()
+    events = [ArrivalEvent(rid=i, arrival_s=at, prompt_len=32 + sfx,
+                           max_new_tokens=gl,
+                           prompt_tokens=shared + rng.integers(
+                               0, cfg.vocab_size, sfx).tolist())
+              for i, (sfx, gl, at) in enumerate(
+                  [(5, 6, 0.0), (9, 8, 0.0), (3, 7, 5.0), (12, 5, 5.0)])]
+    out = {}
+    for tag, trace in (("on", TraceRecorder()), ("off", None)):
+        eng = M2CacheEngine(cfg=cfg, params=params, dram_capacity_gb=0.5,
+                            ssd_dir=str(tmp_path_factory.mktemp(tag)))
+        per_block = 16 * eng.kv_bytes_per_token() / 2 ** 30
+        sched = ContinuousBatchScheduler(
+            eng, max_batch=2, hbm_kv_gb=4 * per_block,
+            dram_kv_gb=2 * per_block, prefill_chunk=16,
+            prefix_caching=True, trace=trace)
+        reqs = requests_from_trace(events)
+        rep = sched.run(reqs)
+        out[tag] = (rep, reqs, trace)
+    return out
+
+
+def test_real_tiny_host_spans_cover_the_served_layers(
+        served_with_and_without_recorder):
+    (rep_on, _, tr), (rep_off, _, _) = (
+        served_with_and_without_recorder[k] for k in ("on", "off"))
+    toks = {k: {r.rid: list(r.session.tokens) for r in rep.requests}
+            for k, rep in (("on", rep_on), ("off", rep_off))}
+    assert toks["on"] == toks["off"] and len(toks["on"]) == 4
+    assert rep_on.modeled_span_s == rep_off.modeled_span_s
+    # the trace exercised every layer the host spans cover
+    names = {f"{e.track}.{e.name}" for e in tr.events() if e.clock == HOST}
+    assert names >= {
+        "sched.iteration", "sched.admit", "sched.idle", "prefix.lock",
+        "prefix.insert", "engine.decode_step", "engine.decode.sync",
+        "engine.decode.dispatch", "engine.decode.m2_manager",
+        "engine.prefill_step", "engine.prefill.dispatch",
+        "engine.prefill.m2_manager", "engine.restore_prefix", "kv.demote",
+        "kv.capture", "kv.spill", "kv.promote", "kv.ssd_read",
+        "kv.deliver"}
+    assert tr.open_spans() == 0 and tr.dropped_events == 0
+
+
+def test_host_stamps_ordered_for_every_request(
+        served_with_and_without_recorder):
+    for tag in ("on", "off"):
+        rep, reqs, _ = served_with_and_without_recorder[tag]
+        assert len(rep.requests) == len(reqs) == 4
+        for r in reqs:
+            assert r.host_released_s <= r.host_admitted_s \
+                <= r.host_first_token_s
+
+
+def test_decode_children_lie_inside_their_step(
+        served_with_and_without_recorder):
+    _, _, tr = served_with_and_without_recorder["on"]
+    host = [e for e in tr.events() if e.clock == HOST]
+    steps = [e for e in host if e.name == "decode_step"]
+    children = [e for e in host if e.name.startswith("decode.")]
+    assert steps and len(children) == 3 * len(steps)
+    for c in children:
+        assert c.args["parent"] == "engine.decode_step"
+        assert len([p for p in steps
+                    if p.t <= c.t and c.t + c.dur <= p.t + p.dur]) == 1
+    assert sum(c.dur for c in children) <= sum(p.dur for p in steps)
+    assert {(p.args or {}).get("parent") for p in steps} == \
+        {"sched.iteration"}
+
+
+# ---------------------------------------------------------------------------
+# stable names of the served programs: a profiler trace finds the decode
+# and prefill steps by these substrings of their lowered module names
+
+
+@pytest.mark.parametrize("program,name", [
+    ("_decode_batched", "decode_one_row"),
+    ("_prefill_block", "prefill_block_one_row"),
+    ("_prefill_block_rows", "prefill_block_one_row"),
+])
+def test_served_program_names_are_stable(tiny_model, program, name):
+    import re
+    import jax.numpy as jnp
+    from repro.core.engine_model import RealModelRunner, _stack_rows
+    from repro.models import transformer as T
+    cfg, params = tiny_model
+    runner = RealModelRunner(cfg, params, max_seq=32)
+    one = T.init_cache(cfg, 1, max_seq=32, dtype=runner.dtype)
+    stack = _stack_rows([one] * 2)
+    args = {
+        "_decode_batched": (stack, jnp.zeros((2, cfg.vocab_size),
+                                             jnp.float32)),
+        "_prefill_block": (one, jnp.zeros((16,), jnp.int32),
+                           jnp.asarray(1, jnp.int32)),
+        "_prefill_block_rows": (stack, jnp.zeros((2, 16), jnp.int32),
+                                jnp.ones((2,), jnp.int32)),
+    }[program]
+    text = getattr(runner, program).lower(params, *args).as_text()
+    module = re.search(r"module @(\S+)", text).group(1)
+    assert name in module, module
+
+
+def test_recorder_outliving_its_scheduler_frees_the_engine(tmp_path):
+    import gc
+    import weakref
+    tr = TraceRecorder()
+    eng = M2CacheEngine(paper_model="llama-7b", dram_capacity_gb=6.0,
+                        ssd_dir=str(tmp_path))
+    sched = ContinuousBatchScheduler(eng, max_batch=2, trace=tr)
+    sched.run(requests_from_trace([ArrivalEvent(
+        rid=0, arrival_s=0.0, prompt_len=8, max_new_tokens=2)]))
+    assert tr.events()
+    dead = weakref.ref(eng)
+    del sched, eng
+    gc.collect()
+    assert dead() is None            # the recorder held on to nothing
