@@ -73,14 +73,19 @@ def pack_int4(q, axis: int = -1):
     return ((lo & 0x0F) | (hi << 4)).astype(jnp.int8)
 
 
+def int4_nibbles(packed):
+    """The sign-extended low (even index) and high (odd index) nibbles of
+    packed int4 values, each the shape of ``packed``."""
+    return (packed << 4) >> 4, packed >> 4   # arithmetic shifts keep sign
+
+
 def unpack_int4(packed, axis: int, orig_len: Optional[int] = None):
     """Inverse of the packing step: int8 (n//2 on axis) -> int4 values (n).
 
     ``orig_len`` trims the unpacked axis back to an odd pre-padding
     length (see :func:`pack_int4`); None keeps the full 2*n values."""
     axis = axis % packed.ndim
-    lo = (packed << 4) >> 4          # sign-extend low nibble
-    hi = packed >> 4                 # arithmetic shift keeps sign
+    lo, hi = int4_nibbles(packed)
     stacked = jnp.stack([lo, hi], axis=axis + 1)
     shape = list(packed.shape)
     shape[axis] *= 2

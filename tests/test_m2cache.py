@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.configs.base import get_config
@@ -122,6 +123,94 @@ def test_mp_ffn_precision_ordering(key):
     e_i4 = _mp_err((0.0, 0.0, 1.0), key)
     assert e_fp < 1e-5                      # pure fp16 == masked reference
     assert e_fp < e_mix < e_i4              # monotone in precision
+
+
+def _gather_form(cfg, banks, pred, x):
+    """The per-row gather formulation of the M2 FFN: gather each tier's
+    active columns (rows of the down projection), dequantize them,
+    concatenate a (d, k) copy per matrix and multiply."""
+    from repro.core.predictor import predictor_scores, shared_topk_indices
+    from repro.models.common import activation
+    d = x.shape[-1]
+    f = banks["wg_i8_s"].shape[-1]
+    sizes = mp_ffn.tier_sizes(f, cfg)
+    k, k16, k8, k4 = sizes["k"], sizes["fp16"], sizes["int8"], sizes["int4"]
+    idx = shared_topk_indices(predictor_scores(x, pred["A"], pred["B"]), k)
+    i16, i8, i4 = idx[:k16], idx[k16:k16 + k8], idx[k16 + k8:]
+    c = x.dtype
+
+    def cols(w, i):
+        return jnp.take(w, i, axis=1)
+
+    def rows(w, i):
+        return jnp.take(w, i, axis=0)
+
+    wg = jnp.concatenate([
+        cols(banks["wg_fp"], i16).astype(c),
+        cols(banks["wg_i8"], i8).astype(c) * banks["wg_i8_s"][i8].astype(c),
+        quantize.unpack_int4(cols(banks["wg_i4"], i4), 0).astype(c)
+        * banks["wg_i4_s"][i4].astype(c)], axis=1)
+    wu = jnp.concatenate([
+        cols(banks["wu_fp"], i16).astype(c),
+        cols(banks["wu_i8"], i8).astype(c) * banks["wu_i8_s"][i8].astype(c),
+        quantize.unpack_int4(cols(banks["wu_i4"], i4), 0).astype(c)
+        * banks["wu_i4_s"][i4].astype(c)], axis=1)
+    wd = jnp.concatenate([
+        rows(banks["wd_fp"], i16).astype(c),
+        rows(banks["wd_i8"], i8).astype(c)
+        * banks["wd_i8_s"][i8].astype(c)[:, None],
+        quantize.unpack_int4(rows(banks["wd_i4"], i4), 1).astype(c)
+        * banks["wd_i4_s"][i4].astype(c)[:, None]], axis=0)
+    act = activation(cfg.ffn_act)
+    h = act(jnp.einsum("bsd,dk->bsk", x, wg)) \
+        * jnp.einsum("bsd,dk->bsk", x, wu)
+    y = jnp.einsum("bsk,kd->bsd", h, wd)
+    return y, {"active_idx": idx,
+               "bytes_weights": 3 * d * (k16 * 2 + k8 * 1 + k4 * 0.5)}
+
+
+@pytest.mark.parametrize("ratios", [(1.0, 0.0, 0.0), (0.25, 0.25, 0.5),
+                                    (0.0, 0.0, 1.0)],
+                         ids=["fp", "mixed", "int4"])
+def test_mp_ffn_matches_gather_form(key, ratios):
+    """The shared per-bank products with a per-row select compute what the
+    per-row column gather computed, to float32 rounding, alone and under
+    the batched runner's vmap over rows with different active sets."""
+    cfg = dataclasses.replace(
+        get_config("qwen2.5-14b", tiny=True), m2_ratio_fp16=ratios[0],
+        m2_ratio_int8=ratios[1], m2_ratio_int4=ratios[2],
+        m2_active_ratio=0.3)
+    d, f = 64, 256
+    ks = jax.random.split(key, 7)
+    banks = quantize.build_neuron_banks(
+        jax.random.normal(ks[0], (d, f)) / np.sqrt(d),
+        jax.random.normal(ks[1], (d, f)) / np.sqrt(d),
+        jax.random.normal(ks[2], (f, d)) / np.sqrt(f))
+    pred = {"A": jax.random.normal(ks[3], (d, 16)),
+            "B": jax.random.normal(ks[4], (16, f))}
+
+    def check(got, want):
+        (y, info), (y0, info0) = got, want
+        tol = 1e-5 * float(jnp.max(jnp.abs(y0)))
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
+                                   rtol=1e-5, atol=tol)
+        np.testing.assert_array_equal(np.asarray(info["active_idx"]),
+                                      np.asarray(info0["active_idx"]))
+        np.testing.assert_array_equal(np.asarray(info["bytes_weights"]),
+                                      np.asarray(info0["bytes_weights"]))
+
+    x = jax.random.normal(ks[5], (1, 1, d))
+    check(mp_ffn.mp_ffn_apply(cfg, banks, pred, x),
+          _gather_form(cfg, banks, pred, x))
+
+    xs = jax.random.normal(ks[6], (4, 1, 1, d))
+
+    def rows(fn):
+        return jax.jit(jax.vmap(lambda r: fn(cfg, banks, pred, r)))(xs)
+
+    got, want = rows(mp_ffn.mp_ffn_apply), rows(_gather_form)
+    assert len({tuple(np.asarray(r)) for r in want[1]["active_idx"]}) == 4
+    check(got, want)
 
 
 def test_tier_sizes_partition():
