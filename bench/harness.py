@@ -12,6 +12,9 @@ waits on the host clock, and no modeled second enters a timestamp. The
 adapter's ``decode_step`` and ``prefill_step`` stamp the host time at which
 each call returned: both read their results back to the host, so a token
 stamped there has reached it.
+
+The harness knows no model: the configuration's architecture module
+(``bench/arch``) makes the program's config, the weights and the reference.
 """
 from __future__ import annotations
 
@@ -27,7 +30,9 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import generator, weights as W
+from bench import generator
+from bench import program_trace as PT
+from bench.arch import resolve
 
 BENCH = Path(__file__).resolve().parent
 GRACE_S = 90.0      # past the window: the most a due request may wait
@@ -77,10 +82,9 @@ class Recorder:
 class HostClockEngine:
     """The real engine, seen by the scheduler on the host clock."""
 
-    def __init__(self, engine, recorder: Recorder, annotate=None):
+    def __init__(self, engine, recorder: Recorder):
         self._engine = engine
         self._rec = recorder
-        self._annotate = annotate
         self.origin = time.perf_counter()
 
     def __getattr__(self, name):
@@ -94,10 +98,9 @@ class HostClockEngine:
         """Modeled seconds (transfer stalls, idle jumps) are not time."""
 
     def decode_step(self, sessions):
-        with _span(self._annotate, "decode_step"):
-            t0 = time.perf_counter()
-            rep = self._engine.decode_step(sessions)
-            t1 = time.perf_counter()
+        t0 = time.perf_counter()
+        rep = self._engine.decode_step(sessions)
+        t1 = time.perf_counter()
         self._rec.on_decode(t0, t1, sessions)
         if self._rec.stop_check is not None:
             self._rec.stop_check(t1)
@@ -105,10 +108,9 @@ class HostClockEngine:
 
     def prefill_step(self, sessions, max_tokens=None):
         before = {id(s): s.prompt_done for s in sessions}
-        with _span(self._annotate, "prefill_step"):
-            t0 = time.perf_counter()
-            rep = self._engine.prefill_step(sessions, max_tokens)
-            t1 = time.perf_counter()
+        t0 = time.perf_counter()
+        rep = self._engine.prefill_step(sessions, max_tokens)
+        t1 = time.perf_counter()
         self._rec.on_prefill(t0, t1, before, sessions)
         if self._rec.stop_check is not None:
             self._rec.stop_check(t1)
@@ -131,21 +133,6 @@ class ReleaseRecorder:
         for r in waiting:
             self.released.setdefault(r.rid, t)
         return self._policy.admission_order(waiting, now)
-
-
-class _span:
-    """A profiler host span named ``bench:<name>`` when ``annotate``."""
-
-    def __init__(self, annotate, name):
-        self._cm = annotate(f"bench:{name}") if annotate else None
-
-    def __enter__(self):
-        if self._cm is not None:
-            self._cm.__enter__()
-
-    def __exit__(self, *exc):
-        if self._cm is not None:
-            self._cm.__exit__(*exc)
 
 
 class CompileCounter:
@@ -188,46 +175,6 @@ class CompileCounter:
             self.compiles += 1
 
 
-class TierTimer:
-    """Host time inside the KV tiers' entry points, each call fenced so
-    that queued device work is waited for on both sides (traced run only).
-    """
-
-    KV_CALLS = ("ensure_resident", "swap_out", "extend", "append_token",
-                "prefetch_resident", "payloads_for")
-
-    def __init__(self, kv, engine, annotate):
-        import jax
-        import jax.numpy as jnp
-        self.seconds = 0.0
-        self.calls = 0
-        self._x = jnp.zeros((8,), jnp.float32)
-        self._fence_fn = jax.jit(lambda x: x + 1)
-        self._annotate = annotate
-        for name in self.KV_CALLS:
-            setattr(kv, name, self._timed(getattr(kv, name), f"kv.{name}"))
-        # the prefix-KV restore into a fresh session cache
-        engine._init_exec = self._timed(engine._init_exec,
-                                        "engine.restore_prefix")
-        self._fence()                   # compiled before the window
-
-    def _fence(self):
-        self._fence_fn(self._x).block_until_ready()
-
-    def _timed(self, fn, name):
-        def call(*a, **kw):
-            self._fence()
-            with _span(self._annotate, name):
-                t0 = time.perf_counter()
-                try:
-                    return fn(*a, **kw)
-                finally:
-                    self._fence()
-                    self.seconds += time.perf_counter() - t0
-                    self.calls += 1
-        return call
-
-
 # ---------------------------------------------------------------------------
 # set-up
 
@@ -240,26 +187,6 @@ def load_config(name: str) -> dict:
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
 
 
-def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    import dataclasses as dc
-    from repro.configs.base import get_config
-    a = cfg["assumed"]
-    return dc.replace(
-        get_config(cfg["program_arch"]),
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=a["head_dim"], ffn_act=cfg["hidden_act"],
-        rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"], qkv_bias=True,
-        m2_enabled=True, m2_active_ratio=a["m2_active_ratio"],
-        m2_ratio_fp16=a["m2_ratio_fp"], m2_ratio_int8=a["m2_ratio_int8"],
-        m2_ratio_int4=a["m2_ratio_int4"],
-        m2_predictor_rank=a["m2_predictor_rank"])
-
-
 def kv_budget_tokens(mix: dict, max_batch: int) -> tuple:
     kv = mix["kv_tokens"]
     hbm = kv["hbm"] if "hbm" in kv else kv["hbm_per_slot"] * max_batch
@@ -267,13 +194,14 @@ def kv_budget_tokens(mix: dict, max_batch: int) -> tuple:
 
 
 def build_scheduler(engine, mix: dict, max_batch: int, recorder: Recorder,
-                    *, hbm_tokens: int, dram_tokens: int, annotate=None):
+                    *, hbm_tokens: int, dram_tokens: int, trace=None):
     """The scheduler ``launch/server.py`` builds (FCFS, lossless KV tiers,
     KV prefetch, the mix's prefix cache and chunk), over the host-clock
-    adapter. Returns (scheduler, adapter, release recorder)."""
+    adapter, with ``trace`` (a ``repro.obs.TraceRecorder``) attached.
+    Returns (scheduler, adapter, release recorder)."""
     from repro.core.carbon import CarbonIntensityTrace
     from repro.serving import ContinuousBatchScheduler, make_policy
-    adapter = HostClockEngine(engine, recorder, annotate)
+    adapter = HostClockEngine(engine, recorder)
     policy = ReleaseRecorder(make_policy("fcfs"))
     per_tok = engine.kv_bytes_per_token()
     sched = ContinuousBatchScheduler(
@@ -283,7 +211,7 @@ def build_scheduler(engine, mix: dict, max_batch: int, recorder: Recorder,
         policy=policy, prefill_chunk=mix["prefill_chunk"],
         carbon_trace=CarbonIntensityTrace.constant(), kv_prefetch=True,
         kv_precision=None, prefix_caching=mix["prefix_cache"],
-        prefix_capacity_tokens=65536)
+        prefix_capacity_tokens=65536, trace=trace)
     return sched, adapter, policy
 
 
@@ -398,7 +326,9 @@ def run(cfg: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
     """Set up, measure for ``seconds`` and check one cell. Returns what
     the window saw (a namespace the metric readers take). ``faults``
     plants a fault in the timed path and ``peaks`` stands in for the
-    device's row of bench/peaks.json (tests only)."""
+    device's row of bench/peaks.json (tests only). A traced run attaches a
+    ``repro.obs.TraceRecorder`` to the window's scheduler, so that the
+    profiler's trace holds the program's own spans."""
     import jax
     from repro.core.engine import M2CacheEngine
     from repro.launch.jax_cache import setup_compile_cache
@@ -408,11 +338,13 @@ def run(cfg: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
     jax.config.update("jax_default_matmul_precision",
                       cfg["assumed"]["matmul_precision"].split(":")[0])
     counter = CompileCounter()
-    D = W.dims(cfg)
+    arch = resolve(cfg)
+    D = arch.dims(cfg)
     block = mix["prefill_chunk"]
     max_batch = cfg["max_batch"]
-    pcfg = program_config(cfg)
-    params = W.program_params(seed, D)
+    pcfg = arch.program_config(cfg)
+    vocab = pcfg.vocab_size
+    params = arch.program_params(seed, D)
     jax.block_until_ready(params)
     ssd_dir = tempfile.mkdtemp(prefix="bench_ssd_")
     try:
@@ -425,21 +357,23 @@ def run(cfg: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
             _plant(engine, faults)
         buckets = generator.buckets(mix)
         compile_programs(engine, params, buckets, max_batch, block)
-        run_warmup(engine, mix, max_batch, buckets, D["V"], seed, block)
-        traffic = generator.build(mix, D["V"], seed, seconds)
+        run_warmup(engine, mix, max_batch, buckets, vocab, seed, block)
+        traffic = generator.build(mix, vocab, seed, seconds)
 
-        annotate = jax.profiler.TraceAnnotation if trace else None
         rec = Recorder()
         hbm_t, dram_t = kv_budget_tokens(mix, max_batch)
+        recorder = None
+        if trace:
+            from repro.obs import TraceRecorder
+            recorder = TraceRecorder()
         sched, adapter, policy = build_scheduler(
             engine, mix, max_batch, rec, hbm_tokens=hbm_t,
-            dram_tokens=dram_t, annotate=annotate)
+            dram_tokens=dram_t, trace=recorder)
         if traffic.documents:
             docs = [generator.Request(rid=1_000_000 + i, arrival_s=0.0,
                                       prompt=d, max_new_tokens=1)
                     for i, d in enumerate(traffic.documents)]
             sched.run(serving_requests(docs))
-        tier = TierTimer(sched.kv, engine, annotate) if trace else None
         rec = Recorder()
         adapter._rec = rec
         reqs = serving_requests(traffic.requests)
@@ -485,11 +419,12 @@ def run(cfg: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
         pre1 = dict(sched.prefix.stats()) if sched.prefix else None
 
         ctx = SimpleNamespace(
-            D=D, cfg=cfg, mix=mix, seconds=seconds, origin=adapter.origin,
-            window_end=window_end[0], setup_s=setup_s, rec=rec,
-            requests=reqs, traffic=traffic, released=policy.released,
-            kv0=kv0, kv1=kv1, pre0=pre0, pre1=pre1, tier=tier,
-            peaks=None, trace=None, device=None)
+            arch=arch, D=D, cfg=cfg, mix=mix, seconds=seconds,
+            origin=adapter.origin, window_end=window_end[0],
+            setup_s=setup_s, rec=rec, requests=reqs, traffic=traffic,
+            released=policy.released, kv0=kv0, kv1=kv1, pre0=pre0,
+            pre1=pre1, recorder=sched.trace, peaks=None,
+            trace=None, program_trace=None, device=None)
         ctx.stopped_at = t_stop
         served = {r.rid: list(r.session.tokens) if r.session else []
                   for r in reqs}
@@ -501,9 +436,7 @@ def run(cfg: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
         # free the program's device state before the reference runs
         for r in reqs:
             r.session = None
-        if tier is not None:
-            ctx.tier = SimpleNamespace(seconds=tier.seconds, calls=tier.calls)
-        del sched, adapter, policy, engine, params, tier
+        del sched, adapter, policy, engine, params
         gc.collect()
         ctx.bytes_in_use_after_free = (
             jax.devices()[0].memory_stats() or {}).get("bytes_in_use")
@@ -512,15 +445,20 @@ def run(cfg: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
 
     if trace:
         from bench import trace as TR
-        ctx.trace = TR.reduce_dir(trace_dir, tr.t0, tr.t1)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        t0 = time.perf_counter()
+        try:
+            ctx.trace = TR.reduce_dir(trace_dir, tr.t0, tr.t1)
+            ctx.program_trace = PT.reduce_dir(trace_dir)
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+        ctx.trace_reduce_s = time.perf_counter() - t0
     ctx.peaks = peaks or load_peaks(jax.devices()[0].device_kind)
     ctx.compiles = counter
     ctx.memory_peak_bytes = stats.get("peak_bytes_in_use")
     ctx.checked = [(r.prompt, served[r.rid]) for r in sample]
     ctx.last_logits = [last[r.rid] for r in sample]
-    ctx.checks = check_outputs(seed, D, ctx.checked, ctx.last_logits,
-                               block, limits)
+    ctx.checks = check_outputs(arch, seed, D, vocab, ctx.checked,
+                               ctx.last_logits, block, limits)
     return ctx
 
 
@@ -545,25 +483,26 @@ def _sample(traffic, served, n: int, seed: int) -> list:
     return [longest] + pick
 
 
-def check_outputs(seed, D, pairs, last_logits, block, limits) -> dict:
-    """Compare the sampled requests with the plain reference.
+def check_outputs(arch, seed, D, vocab, pairs, last_logits, block,
+                  limits) -> dict:
+    """Compare the sampled requests with the architecture's plain
+    reference.
 
     ``pairs`` are (prompt, served tokens) and ``last_logits`` the logits
     each request's last decode step left. Two numbers: the widest gap by
     which a served token's reference logit lies below the reference's best
     at its position, and the median over the requests of the RMS deviation
     of the last logits from the reference's."""
-    from bench import reference as R
     n_tok = sum(len(s) for _, s in pairs)
-    out_of_vocab = sum(int(t < 0 or t >= D["V"]) for _, s in pairs
+    out_of_vocab = sum(int(t < 0 or t >= vocab) for _, s in pairs
                        for t in s)
     gap = rms = float("inf")
     if pairs and not out_of_vocab:
-        ref = R.logits(seed, D, pairs, block=block)
-        gap = max(float(R.served_gaps(lg, s).max())
+        ref = arch.logits(seed, D, pairs, block=block)
+        gap = max(float(arch.served_gaps(lg, s).max())
                   for lg, (_, s) in zip(ref, pairs))
-        rms = float(np.median([R.rms_deviation(p, lg[len(s)]) for p, lg, (
-            _, s) in zip(last_logits, ref, pairs)]))
+        rms = float(np.median([arch.rms_deviation(p, lg[len(s)]) for p, lg,
+                               (_, s) in zip(last_logits, ref, pairs)]))
     return {"requests_checked": len(pairs), "tokens_checked": n_tok,
             "served_gap": {"value": gap,
                            "limit": limits["served_gap"]["limit"]},

@@ -63,11 +63,13 @@ def read_metrics(metrics: list, ctx) -> dict:
 
 
 def breakdown(ctx) -> dict:
-    red = ctx.trace
-    mods = sorted(red.modules.items(), key=lambda kv: -kv[1][1])[:10]
-    idle = sorted(red.idle_by_host.items(), key=lambda kv: -kv[1])[:10]
+    """The device programs that took most time, and the device's idle by
+    the program's own innermost host span (nine labels and
+    ``uncovered``)."""
+    from bench import program_trace as PT
+    mods = sorted(ctx.trace.modules.items(), key=lambda kv: -kv[1][1])[:10]
     return {"device_ops": [[n, s] for n, (_, s) in mods],
-            "idle_gaps": [[n, s] for n, s in idle]}
+            "idle_gaps": PT.top(ctx.program_trace.idle_by_program, 9)}
 
 
 def main(argv=None) -> int:
@@ -130,6 +132,11 @@ def main(argv=None) -> int:
                                       for r in Wn.admitted(ctx))}))
     _say("stopped_after_window_s", ctx.stopped_at - ctx.window_end)
     _say("device_bytes_in_use_before_reference", ctx.bytes_in_use_after_free)
+    if ctx.recorder is not None:
+        _say("program_trace", json.dumps({
+            "events": ctx.recorder.total_events,
+            "dropped": ctx.recorder.dropped_events,
+            "reduce_s": ctx.trace_reduce_s}))
 
     metrics = read_metrics(metrics_for(spec, args.workload,
                                        bool(args.trace)), ctx)

@@ -5,18 +5,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import harness as H
 from bench import reference as R
 from bench import weights as W
+from bench.arch import qwen2 as Q
 from bench.tests import tiny
 
 
 def test_program_layout_matches_the_program():
     from repro.models import transformer as T
-    D = W.dims(tiny.CONFIG)
+    D = Q.dims(tiny.CONFIG)
     ours = jax.eval_shape(lambda k: W._program_params(k, W._Frozen(D)),
                           W.base_key(0))
-    theirs = T.abstract_params(H.program_config(tiny.CONFIG),
+    theirs = T.abstract_params(Q.program_config(tiny.CONFIG),
                                dtype=jnp.float32, m2=True)
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
@@ -24,7 +24,7 @@ def test_program_layout_matches_the_program():
 
 
 def test_layer_weights_equal_the_stacked_ones():
-    D = W.dims(tiny.CONFIG)
+    D = Q.dims(tiny.CONFIG)
     seed = 2 ** 33 + 1
     full = W.program_params(seed, D)
     for layer in range(D["L"]):
@@ -36,7 +36,7 @@ def test_layer_weights_equal_the_stacked_ones():
 
 
 def test_int4_packing_round_trips():
-    D = W.dims(tiny.CONFIG)
+    D = Q.dims(tiny.CONFIG)
     lw = W.layer_weights(W.base_key(5), D, 0)
     b = lw["ffn"]["banks"]
     q, _ = W.quantize(b["wg_fp"], 0, 7.0)

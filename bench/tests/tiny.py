@@ -3,7 +3,8 @@ as the benchmark's own files."""
 import copy
 
 CONFIG = {
-    "name": "tiny", "program_arch": "qwen2.5-14b",
+    "name": "tiny", "architectures": ["Qwen2ForCausalLM"],
+    "program_arch": "qwen2.5-14b",
     "num_hidden_layers": 2, "hidden_size": 256, "intermediate_size": 512,
     "num_attention_heads": 8, "num_key_value_heads": 2, "vocab_size": 512,
     "hidden_act": "silu", "rope_theta": 1000000.0,

@@ -1,5 +1,5 @@
-"""Reduce a profiler trace to device busy time, program time and the host
-activity behind idle gaps.
+"""Reduce a profiler trace to device busy time and program time (the host
+activity behind the device's idle gaps is ``bench/program_trace.py``'s).
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into a flat
 list of events; ``reduce`` works on that list alone, so it can be checked
@@ -7,7 +7,6 @@ on a small recorded trace (``bench/tests/data``).
 """
 from __future__ import annotations
 
-import bisect
 import glob
 import json
 import os
@@ -16,22 +15,20 @@ from typing import Dict, List
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_PREFIX = "bench:"
 
 
 def load(path: str) -> List[dict]:
-    """Device ops and modules, and the harness's host spans, as dicts
-    with plane, line, name, start_ns and dur_ns."""
+    """Device ops and modules as dicts with plane, line, name, start_ns
+    and dur_ns."""
     from jax.profiler import ProfileData
     out = []
     for plane in ProfileData.from_file(path).planes:
-        device = plane.name.startswith("/device:")
+        if not plane.name.startswith("/device:"):
+            continue
         for line in plane.lines:
-            if device and line.name not in (OPS_LINE, MODULES_LINE):
+            if line.name not in (OPS_LINE, MODULES_LINE):
                 continue
             for ev in line.events:
-                if not device and not ev.name.startswith(HOST_PREFIX):
-                    continue
                 out.append({"plane": plane.name, "line": line.name,
                             "name": ev.name, "start_ns": ev.start_ns,
                             "dur_ns": ev.duration_ns})
@@ -50,19 +47,15 @@ def _union(intervals):
 
 def reduce(events: List[dict], window_s: float) -> SimpleNamespace:
     """busy_s: union of op intervals, averaged over the device planes that
-    ran anything; modules: {name: [count, seconds]}; idle_by_host: seconds
-    of device idle between ops, by the host span that overlaps most of each
-    gap ("scheduler" where no harness span does)."""
+    ran anything; modules: {name: [count, seconds]}."""
     planes = sorted({e["plane"] for e in events
                      if e["plane"].startswith("/device:")})
     busy = 0.0
-    gaps = []
     for p in planes:
         ops = [(e["start_ns"], e["start_ns"] + e["dur_ns"]) for e in events
                if e["plane"] == p and e["line"] == OPS_LINE]
         merged = _union(ops)
         busy += sum(b - a for a, b in merged) / 1e9
-        gaps += [(a[1], b[0]) for a, b in zip(merged, merged[1:])]
     busy /= max(len(planes), 1)
     modules: Dict[str, list] = {}
     for e in events:
@@ -70,21 +63,8 @@ def reduce(events: List[dict], window_s: float) -> SimpleNamespace:
             m = modules.setdefault(e["name"], [0, 0.0])
             m[0] += 1
             m[1] += e["dur_ns"] / 1e9
-    host = sorted((e["start_ns"], e["start_ns"] + e["dur_ns"], e["name"])
-                  for e in events if not e["plane"].startswith("/device:"))
-    starts = [h[0] for h in host]
-    longest = max((t - s for s, t, _ in host), default=0)
-    idle: Dict[str, float] = {}
-    for a, b in gaps:
-        best, label = 0, "scheduler"
-        lo = bisect.bisect_left(starts, a - longest)
-        for s, t, name in host[lo:bisect.bisect_left(starts, b)]:
-            ov = min(b, t) - max(a, s)
-            if ov > best:
-                best, label = ov, name[len(HOST_PREFIX):]
-        idle[label] = idle.get(label, 0.0) + (b - a) / 1e9
     return SimpleNamespace(busy_s=busy, window_s=window_s, modules=modules,
-                           idle_by_host=idle, planes=len(planes))
+                           planes=len(planes))
 
 
 def module_seconds(red, key: str) -> tuple:

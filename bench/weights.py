@@ -1,4 +1,5 @@
-"""Seeded weights for a benchmark configuration, made on the device.
+"""Seeded weights for the Qwen2 family (``bench/arch/qwen2.py``, which maps a
+configuration file onto the sizes ``D`` taken here), made on the device.
 
 The benchmark makes the weights, not the program: ``program_params`` returns
 the served path's parameter pytree (float32 weights, the M2 FFN as its three
@@ -24,24 +25,6 @@ def base_key(seed: int):
     words = np.random.SeedSequence(int(seed)).generate_state(2)
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32),
                                     impl="threefry2x32")
-
-
-def dims(cfg: dict) -> dict:
-    """The sizes the weights need, from a configuration file's dict."""
-    d = cfg["hidden_size"]
-    hq = cfg["num_attention_heads"]
-    hd = cfg["assumed"]["head_dim"]
-    f = cfg["intermediate_size"]
-    k = min(max(int(round(f * cfg["assumed"]["m2_active_ratio"])), 8), f)
-    k_fp = int(round(k * cfg["assumed"]["m2_ratio_fp"]))
-    k_i8 = int(round(k * cfg["assumed"]["m2_ratio_int8"]))
-    return {"d": d, "f": f, "hq": hq, "hkv": cfg["num_key_value_heads"],
-            "hd": hd, "V": cfg["vocab_size"],
-            "L": cfg["num_hidden_layers"],
-            "r": cfg["assumed"]["m2_predictor_rank"],
-            "k": k, "k_fp": k_fp, "k_i8": k_i8, "k_i4": k - k_fp - k_i8,
-            "eps": cfg["assumed"]["rms_norm_eps"],
-            "theta": cfg["rope_theta"]}
 
 
 def _normal(key, i, shape, std):
